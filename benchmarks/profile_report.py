@@ -165,14 +165,11 @@ def measure_total(K: int, div: int, capacity: int, depth: int,
                    lambda: simulate(reqs, ta, SimParams.make(1), **kw))
     assert int(cw.result.event_overflow) == 0
     if trace_dir is not None:
-        try:
-            with jax.profiler.trace(trace_dir):
-                jax.block_until_ready(
-                    simulate(reqs, ta, SimParams.make(1), **kw))
-            print(f"# jax.profiler trace written under {trace_dir} "
-                  f"(load in ui.perfetto.dev)")
-        except Exception as e:          # profiling is best-effort extra
-            print(f"# jax.profiler trace skipped: {type(e).__name__}: {e}")
+        with jax.profiler.trace(trace_dir):
+            jax.block_until_ready(
+                simulate(reqs, ta, SimParams.make(1), **kw))
+        print(f"# jax.profiler trace written under {trace_dir} "
+              f"(load in ui.perfetto.dev)")
     return cw, max_events, R
 
 

@@ -23,6 +23,9 @@ def load_results(outdir: str = "results/dryrun") -> List[dict]:
 
 def table(outdir: str = "results/dryrun_final",
           mesh: Optional[str] = None) -> List[Tuple[str, float, str]]:
+    if not Path(outdir).is_dir():
+        raise FileNotFoundError(f"{outdir}: no dry-run results (run "
+                                f"python -m repro.launch.dryrun first)")
     rows = []
     for r in load_results(outdir):
         if r.get("status") == "skipped":

@@ -5,6 +5,12 @@ Prints ``name,us_per_call,derived`` CSV (assignment contract).
     PYTHONPATH=src python -m benchmarks.run             # full suite
     PYTHONPATH=src python -m benchmarks.run --quick     # fewer seeds
     PYTHONPATH=src python -m benchmarks.run --only fig5
+    PYTHONPATH=src python -m benchmarks.run --only roofline   # opt-in
+
+A section that raises is reported as ``<name>_FAILED`` and the run goes
+on to the next one, but the command then exits 1.  ``roofline`` reads
+the artifacts of a ``repro.launch.dryrun`` run (``results/dryrun_final``)
+and runs only as ``--only roofline``.
 """
 from __future__ import annotations
 
@@ -22,6 +28,9 @@ def main() -> None:
                     help="substring filter on section names")
     args = ap.parse_args()
     seeds = args.seeds or (3 if args.quick else 10)
+
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     sections = []
 
@@ -49,10 +58,12 @@ def main() -> None:
     sections.append(("scan_profile", lambda: profile_report.run(
         smoke=args.quick,
         json_path=None if args.quick else profile_report.JSON_DEFAULT)))
-    sections.append(("roofline", lambda: roofline_report.table(
-        "results/dryrun_final")))
+    if args.only == "roofline":     # needs a repro.launch.dryrun run first
+        sections.append(("roofline", lambda: roofline_report.table(
+            "results/dryrun_final")))
 
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in sections:
         if args.only and args.only not in name:
             continue
@@ -61,11 +72,14 @@ def main() -> None:
             for row in fn():
                 n, us, derived = row
                 print(f"{n},{us:.2f},{derived}")
-        except Exception as e:   # keep the suite going; report the failure
+        except Exception as e:   # keep the suite going; fail at the end
+            failed.append(name)
             print(f"{name}_FAILED,0,{type(e).__name__}: {e}", file=sys.stderr)
             print(f"{name}_FAILED,0,{type(e).__name__}")
         print(f"# section {name} took {time.time() - t0:.1f}s",
               file=sys.stderr)
+    if failed:
+        sys.exit(f"failed sections: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -207,6 +207,20 @@ def _retire(state: EventState, t, R: int) -> EventState:
     return jax.lax.while_loop(cond, body, state)
 
 
+def _row_windows(a, w0, W: int):
+    """``a[k, w0[k]:w0[k] + W]`` for every row ``k`` (``0 <= w0 <=
+    a.shape[1] - W``) as a barrel shift: one static slice-and-select per
+    bit of ``w0``.  Pure data movement, so bit-identical to the per-row
+    gather it replaces — which the TPU runs element by element, and
+    which cost milliseconds per scan step at 256 nodes."""
+    nb = (a.shape[1] - W).bit_length()
+    a = jnp.pad(a, ((0, 0), (0, (1 << nb) - 1 - (a.shape[1] - W))))
+    for b in range(nb):
+        s = 1 << b
+        a = jnp.where((w0 >> b & 1)[:, None] == 1, a[:, s:], a[:, :-s])
+    return a
+
+
 # ---------------------------------------------------------------------------
 # routing policies: pure selects over (load, adjacency, rng, trace row).
 # Consulted at true event time — every earlier arrival, completion and
@@ -338,8 +352,7 @@ def _estep(state: EventState, _, *, topo: TopologyArrays, key, policy: str,
         # and load-bearing inside: the selected node picks which latency /
         # inverse-bandwidth row the scoring reads).
         w0_all = jnp.clip(state.head, 0, capacity - W)
-        cols = w0_all[:, None] + jnp.arange(W)[None, :]
-        win_all = lambda a: jnp.take_along_axis(a, cols, axis=1)
+        win_all = lambda a: _row_windows(a, w0_all, W)
         hrel_all = state.head - w0_all
         lat, ibw = (net.latency, net.inv_bw) if use_network \
             else (zero_net, zero_net)

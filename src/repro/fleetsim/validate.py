@@ -255,6 +255,18 @@ def run_validation(scenario: str = "paper/scenario1", seed: int = 0,
         capacity=capacity, telemetry=agreement)
 
 
+def contract_violations(reports: List[ValidationReport]
+                        ) -> List[ValidationReport]:
+    """The cells that break the contract: beyond the f32-boundary
+    allowance (0.5pp met-rate, 0.5% of requests per field) or, with
+    telemetry, any bucket disagreement."""
+    return [r for r in reports
+            if r.met_diff_pp > 0.5
+            or r.outcome_mismatches > 0.005 * r.total
+            or r.node_mismatches > 0.005 * r.total
+            or (r.telemetry is not None and not r.telemetry.ok)]
+
+
 def main() -> List[ValidationReport]:
     import argparse
     ap = argparse.ArgumentParser()
@@ -293,11 +305,7 @@ def main() -> List[ValidationReport]:
             print(rep.row(), flush=True)
     worst = max(r.met_diff_pp for r in reports)
     n_exact = sum(r.exact for r in reports)
-    violations = [r for r in reports
-                  if r.met_diff_pp > 0.5
-                  or r.outcome_mismatches > 0.005 * r.total
-                  or r.node_mismatches > 0.005 * r.total
-                  or (r.telemetry is not None and not r.telemetry.ok)]
+    violations = contract_violations(reports)
     print(f"# {n_exact}/{len(reports)} cells exact; "
           f"worst met-rate delta {worst:.3f}pp "
           f"(contract: exact or <= 0.5pp f32-boundary flips, "

@@ -36,48 +36,50 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BIG = 1e30
 
 
-def _event_select_kernel(ta_ref, na_ref, da_ref, pa_ref, ya_ref, aa_ref,
-                         tb_ref, nb_ref, db_ref, pb_ref, yb_ref, ab_ref,
+def _event_select_kernel(fsc_ref, isc_ref,
                          starts_ref, ends_ref, sizes_ref, n_ref, head_ref,
-                         speeds_ref, busy_ref, lat_ref, invbw_ref,
-                         takea_ref, tsel_ref, nsel_ref,
+                         ps_a_ref, ps_b_ref, busy_ref, lat_ref, invbw_ref,
+                         isel_ref, tsel_ref,
                          feas_ref, arr_ref, j_ref, cap_ref, load_ref,
                          *, eps: float):
-    # -- the merge: earliest of (fresh candidate a, buffer head b); fresh
-    # wins ties (host heap seq order — see module docstring)
-    avail_a = aa_ref[0, 0] != 0
-    avail_b = ab_ref[0, 0] != 0
-    take_a = avail_a & ((ta_ref[0, 0] <= tb_ref[0, 0]) | ~avail_b)
-    t = jnp.where(take_a, ta_ref[0, 0], tb_ref[0, 0])
-    node = jnp.where(take_a, na_ref[0, 0], nb_ref[0, 0])
-    d = jnp.where(take_a, da_ref[0, 0], db_ref[0, 0])
-    p = jnp.where(take_a, pa_ref[0, 0], pb_ref[0, 0])
-    payload = jnp.where(take_a, ya_ref[0, 0], yb_ref[0, 0])
+    # -- the merge, on the scalar unit: earliest of (fresh candidate a,
+    # buffer head b); fresh wins ties (host heap seq order — see module
+    # docstring).  SMEM rows: fsc = (t, d, payload) of a then of b,
+    # isc = (node, avail) of a then of b
+    avail_a = isc_ref[0, 1] != 0
+    avail_b = isc_ref[0, 3] != 0
+    take_a = avail_a & ((fsc_ref[0, 0] <= fsc_ref[0, 3]) | ~avail_b)
+    pick = lambda ref, i, w: jnp.where(take_a, ref[0, i], ref[0, i + w])
+    t, d, payload = (pick(fsc_ref, i, 3) for i in range(3))
+    node = pick(isc_ref, 0, 2)
 
     starts = starts_ref[...]                     # (bk, N)
     ends = ends_ref[...]
     sizes = sizes_ref[...]
     n = n_ref[...]                               # (bk, 1) int32
     head = head_ref[...]                         # (bk, 1) int32
-    speeds = speeds_ref[...]                     # (bk, 1)
+    ps = jnp.where(take_a, ps_a_ref[...], ps_b_ref[...])     # (bk, 1)
     busy = busy_ref[...]                         # (bk, 1)
-    lat = lat_ref[...]                           # (K, bk)
-    invbw = invbw_ref[...]                       # (K, bk)
+    lat = lat_ref[...]                           # (bk, K): lat[src, cand].T
+    invbw = invbw_ref[...]                       # (bk, K)
     bk, N = starts.shape
-    K = lat.shape[0]
+    K = lat.shape[1]
     tail = head + n
     idx = jax.lax.broadcasted_iota(jnp.int32, (bk, N), 1)
-    ps = p / speeds
 
-    # -- source row gather as a one-hot masked sum (the selected node is a
-    # traced scalar; exactly one row matches, and 0.0 + v == v exactly)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (K, bk), 0)
-    lat_row = jnp.sum(jnp.where(rows == node, lat, 0.0), axis=0)[:, None]
-    ibw_row = jnp.sum(jnp.where(rows == node, invbw, 0.0), axis=0)[:, None]
+    # -- source column gather as a one-hot masked lane sum (the selected
+    # node is a traced scalar; exactly one column matches, and 0.0 + v == v
+    # exactly)
+    srcs = jax.lax.broadcasted_iota(jnp.int32, (bk, K), 1)
+    lat_row = jnp.sum(jnp.where(srcs == node, lat, 0.0), axis=1,
+                      keepdims=True)
+    ibw_row = jnp.sum(jnp.where(srcs == node, invbw, 0.0), axis=1,
+                      keepdims=True)
     arrive = t + lat_row + payload * ibw_row
     free = jnp.maximum(arrive, busy)
 
@@ -87,8 +89,7 @@ def _event_select_kernel(ta_ref, na_ref, da_ref, pa_ref, ya_ref, aa_ref,
     cap_idx = jnp.sum((starts < d).astype(jnp.int32), axis=1, keepdims=True)
     e_hi = jnp.sum((ends < d).astype(jnp.int32), axis=1, keepdims=True)
 
-    prev_ends = jnp.concatenate(
-        [jnp.full((bk, 1), -BIG, ends.dtype), ends[:, :-1]], axis=1)
+    prev_ends = jnp.where(idx == 0, -BIG, pltpu.roll(ends, 1, 1))
     has_gap = (starts > prev_ends) & (idx >= head + 1) & (idx < tail)
     gap_ok = has_gap & (idx <= e_hi)
     prev_gap = jnp.max(jnp.where(gap_ok, idx, head), axis=1, keepdims=True)
@@ -110,9 +111,9 @@ def _event_select_kernel(ta_ref, na_ref, da_ref, pa_ref, ya_ref, aa_ref,
     pw_j = jnp.sum(jnp.where(idx < j, sizes, 0.0), axis=1, keepdims=True)
     feasible = (cap - (free + pw_j) >= ps - eps) & (cap > free) & (tail < N)
 
-    takea_ref[0, 0] = take_a.astype(jnp.int32)
+    isel_ref[0, 0] = take_a.astype(jnp.int32)
+    isel_ref[0, 1] = node
     tsel_ref[0, 0] = t
-    nsel_ref[0, 0] = node
     feas_ref[...] = feasible.astype(jnp.int32)
     arr_ref[...] = arrive
     j_ref[...] = j
@@ -140,6 +141,14 @@ def event_select_fwd(t_a, node_a, d_a, p_a, pay_a, avail_a,
     scores itself at its true arrival ``t``).  ``head`` marks retired
     slots (fleetsim head-pointer rows; default 0 == plain Ledger).
 
+    Layout for Mosaic: the candidate scalars ride two packed SMEM rows and
+    the merge verdict comes back through SMEM; every vector operand is
+    blocked by whole rows of ``block_nodes`` candidates — ledger windows
+    ``(bk, N)``, per-node columns ``(bk, 1)`` and the *transposed* net
+    tensors ``(bk, K)`` — so each block's last dim is the array's full
+    width.  The per-candidate scaled work ``p / speeds`` is divided here,
+    outside the kernel, with the same op as the oracle.
+
     Returns ``(take_fresh, t, node, feasible (K,), arrive (K,), j (K,),
     cap (K,), load (K,))`` — oracle:
     :func:`repro.kernels.ref.event_select_ref`.
@@ -154,36 +163,36 @@ def event_select_fwd(t_a, node_a, d_a, p_a, pay_a, avail_a,
                        constant_values=fill) if pad else x
 
     dtype = starts.dtype
-    fscalar = lambda x: jnp.asarray(x, dtype).reshape(1, 1)
-    iscalar = lambda x: jnp.asarray(x, jnp.int32).reshape(1, 1)
+    fsc = jnp.stack([jnp.asarray(x, dtype) for x in
+                     (t_a, d_a, pay_a, t_b, d_b, pay_b)]).reshape(1, 6)
+    isc = jnp.stack([jnp.asarray(x, jnp.int32) for x in
+                     (node_a, avail_a, node_b, avail_b)]).reshape(1, 4)
     col = lambda x, f: pad_rows(jnp.asarray(x, dtype).reshape(K, 1), f)
+    speeds = jnp.asarray(speeds, dtype).reshape(K)
     ncol = pad_rows(n.astype(jnp.int32).reshape(K, 1), 0)
     hcol = pad_rows(jnp.zeros((K, 1), jnp.int32) if head is None
                     else head.astype(jnp.int32).reshape(K, 1), 0)
-    # (K, K) net tensors padded on columns only: each program reads the
-    # full row space but just its candidate-block of columns
-    net_pad = lambda x: jnp.pad(jnp.asarray(x, dtype), ((0, 0), (0, pad))) \
-        if pad else jnp.asarray(x, dtype)
-    bs_scalar = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    # (K, K) net tensors transposed so candidates run down the rows: each
+    # program reads its candidate block's full source rows
+    net_t = lambda x: pad_rows(jnp.asarray(x, dtype).T, 0.0)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     bs_rows = pl.BlockSpec((block_nodes, N), lambda i: (i, 0))
     bs_col = pl.BlockSpec((block_nodes, 1), lambda i: (i, 0))
-    bs_net = pl.BlockSpec((K, block_nodes), lambda i: (0, i))
+    bs_net = pl.BlockSpec((block_nodes, K), lambda i: (i, 0))
     KB = grid * block_nodes
-    take_a, t_sel, n_sel, feas, arr, j, cap, load = pl.pallas_call(
+    isel, t_sel, feas, arr, j, cap, load = pl.pallas_call(
         functools.partial(_event_select_kernel, eps=eps),
         grid=(grid,),
-        in_specs=[bs_scalar] * 12 + [
-            bs_rows, bs_rows, bs_rows,           # starts, ends, sizes
-            bs_col, bs_col,                      # n, head
-            bs_col, bs_col,                      # speeds, busy
-            bs_net, bs_net,                      # latency, inv_bw
-        ],
-        out_specs=[bs_scalar, bs_scalar, bs_scalar,
+        in_specs=[smem, smem,
+                  bs_rows, bs_rows, bs_rows,     # starts, ends, sizes
+                  bs_col, bs_col,                # n, head
+                  bs_col, bs_col, bs_col,        # ps_a, ps_b, busy
+                  bs_net, bs_net],               # latency.T, inv_bw.T
+        out_specs=[smem, smem,
                    bs_col, bs_col, bs_col, bs_col, bs_col],
         out_shape=[
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((1, 2), jnp.int32),
             jax.ShapeDtypeStruct((1, 1), dtype),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
             jax.ShapeDtypeStruct((KB, 1), jnp.int32),
             jax.ShapeDtypeStruct((KB, 1), dtype),
             jax.ShapeDtypeStruct((KB, 1), jnp.int32),
@@ -191,12 +200,10 @@ def event_select_fwd(t_a, node_a, d_a, p_a, pay_a, avail_a,
             jax.ShapeDtypeStruct((KB, 1), dtype),
         ],
         interpret=interpret,
-    )(fscalar(t_a), iscalar(node_a), fscalar(d_a), fscalar(p_a),
-      fscalar(pay_a), iscalar(avail_a),
-      fscalar(t_b), iscalar(node_b), fscalar(d_b), fscalar(p_b),
-      fscalar(pay_b), iscalar(avail_b),
+    )(fsc, isc,
       pad_rows(starts, BIG), pad_rows(ends, BIG), pad_rows(sizes, 0.0),
-      ncol, hcol, col(speeds, 1.0), col(busy, 0.0),
-      net_pad(latency), net_pad(inv_bw))
-    return (take_a[0, 0] != 0, t_sel[0, 0], n_sel[0, 0],
+      ncol, hcol, col(jnp.asarray(p_a, dtype) / speeds, 0.0),
+      col(jnp.asarray(p_b, dtype) / speeds, 0.0), col(busy, 0.0),
+      net_t(latency), net_t(inv_bw))
+    return (isel[0, 0] != 0, t_sel[0, 0], isel[0, 1],
             feas[:K, 0] != 0, arr[:K, 0], j[:K, 0], cap[:K, 0], load[:K, 0])

@@ -1,13 +1,15 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — Python
-evaluation of the kernel body, used for correctness validation.  On a real
-TPU backend ``interpret`` flips to False and the same BlockSpecs compile to
-Mosaic.  Model code selects kernels via the config's ``attn_impl='pallas'``.
+On a TPU backend the kernels compile to Mosaic.  On any other backend they
+run in interpret mode — the kernel body evaluated as ordinary XLA, used
+for correctness validation — and say so with a ``RuntimeWarning`` when
+they are traced.  Model code selects kernels via the config's
+``attn_impl='pallas'``.
 """
 from __future__ import annotations
 
 import functools
+import warnings
 from typing import Optional
 
 import jax
@@ -22,7 +24,13 @@ from repro.kernels import rmsnorm as _rn
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    warnings.warn(f"Pallas kernels run in interpret mode on the "
+                  f"{backend!r} backend (no TPU)", RuntimeWarning,
+                  stacklevel=2)
+    return True
 
 
 def _pad_to(x: jnp.ndarray, mult: int, axis: int) -> jnp.ndarray:

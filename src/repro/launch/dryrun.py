@@ -22,6 +22,7 @@ from pathlib import Path  # noqa: E402
 import jax               # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
+from repro.compile_cache import use_compile_cache  # noqa: E402
 from repro.configs import all_cells, get_config, shapes_for  # noqa: E402
 from repro.distributed import sharding as shd  # noqa: E402
 from repro.launch import roofline  # noqa: E402
@@ -162,6 +163,7 @@ def main() -> None:
     ap.add_argument("--tag", default="",
                     help="suffix for the result file name")
     args = ap.parse_args()
+    use_compile_cache()
     overrides = {}
     for kv in args.override:
         k, v = kv.split("=", 1)

@@ -24,11 +24,13 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.compile_cache import use_compile_cache
     from repro.configs import get_config, get_smoke_config
     from repro.configs.shapes import ShapeSpec
     from repro.launch import steps as S
     from repro.training.train_loop import TrainLoopConfig, run
 
+    use_compile_cache()
     cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
     if cfg.family == "lm":
         shape = ShapeSpec("cli", "train", seq_len=args.seq,

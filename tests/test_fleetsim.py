@@ -172,6 +172,20 @@ def test_simulate_use_pallas_matches_ref_path():
     assert int(a.met_deadline) == int(b.met_deadline)
 
 
+@pytest.mark.parametrize("C,W", [(1024, 512), (256, 128), (128, 128),
+                                 (257, 256), (100, 37)])
+def test_row_windows_match_per_row_gather(C, W):
+    from repro.fleetsim.core import _row_windows
+    rng = np.random.default_rng(C + W)
+    a = jnp.asarray(rng.standard_normal((6, C)), jnp.float32)
+    w0 = rng.integers(0, C - W + 1, 6)
+    w0[:2] = 0, C - W                          # both ends of the range
+    w0 = jnp.asarray(w0, jnp.int32)
+    want = jnp.take_along_axis(a, w0[:, None] + jnp.arange(W)[None], axis=1)
+    assert np.array_equal(np.asarray(_row_windows(a, w0, W)),
+                          np.asarray(want))
+
+
 # ---------------------------------------------------------------------------
 # jax_queue generalizations backing the fleet state
 # ---------------------------------------------------------------------------

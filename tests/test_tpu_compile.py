@@ -1,0 +1,103 @@
+"""The chip's compiler on the main path, without a chip.
+
+Each test compiles for one chip of a *described* TPU v5e (2x2) host:
+the Mosaic ``event_select`` kernel at fleet widths, the 256-node
+fleetsim scan (jnp path and kernel path) and the deit-b forward at its
+published widths.  Nothing runs; a compile that passes here is not a
+chip run.  It catches what interpret mode cannot: block shapes the TPU
+refuses, scalars in vector memory, programs that do not fit.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and the workers of a
+parallel test run all import this file.  The persistent compilation
+cache is off around these compiles, since an entry written for a
+described chip cannot be read back without one.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+KERNEL = "tpu_custom_call"
+
+# the 256-node benchmark fleet (benchmarks/fleetsim_bench.py,
+# make_fleet_workload(256, 4)): 128,000 requests, capacity 1024, depth 512
+FLEET_K, FLEET_R, FLEET_CAP, FLEET_DEPTH = 256, 128_000, 1024, 512
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("K", [32, 256])
+def test_event_select_compiles_for_v5e(one_chip, K):
+    from repro.kernels.event_select import event_select_fwd
+    N = FLEET_DEPTH
+    s = lambda shape=(), dt=jnp.float32: _spec(one_chip, shape, dt)
+    cand = (s(), s((), jnp.int32), s(), s(), s(), s((), jnp.bool_))
+    args = (*cand, *cand, s((K, N)), s((K, N)), s((K, N)),
+            s((K,), jnp.int32), s((K,), jnp.int32), s((K,)), s((K,)),
+            s((K, K)), s((K, K)))
+    fn = jax.jit(lambda *a: event_select_fwd(*a, interpret=False))
+    compiled = fn.lower(*args).compile()
+    assert KERNEL in compiled.as_text()
+
+
+@pytest.mark.parametrize("policy,use_pallas", [
+    ("random", False), ("batched_feasible", True)])
+def test_fleet_scan_compiles_for_v5e(one_chip, monkeypatch, policy,
+                                    use_pallas):
+    from repro.fleetsim import (RequestArrays, SimParams, TopologyArrays,
+                                simulate_fn)
+    from repro.kernels import ops
+    # ops picks interpret mode from the default backend, which is the CPU
+    # here; the target of this compile is the described chip
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    s = lambda shape=(), dt=jnp.float32: _spec(one_chip, shape, dt)
+    K, R = FLEET_K, FLEET_R
+    reqs = RequestArrays(s((R,)), s((R,)), s((R,)), s((R,), jnp.int32),
+                         s((R,), jnp.int32), s((R,)))
+    topo = TopologyArrays(s((K, K), jnp.bool_), s((K, K - 1), jnp.int32),
+                          s((K,), jnp.int32), s((K,)))
+    params = SimParams(s((), jnp.int32), s(()))
+    run = simulate_fn(policy=policy, capacity=FLEET_CAP, depth=FLEET_DEPTH,
+                      use_pallas=use_pallas)
+    compiled = jax.jit(run).lower(reqs, topo, params,
+                                  s((R, 2), jnp.int32)).compile()
+    assert (KERNEL in compiled.as_text()) == use_pallas
+    # the whole fleet state fits one chip's 16 GB with room to spare
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_deit_b_forward_compiles_for_v5e(one_chip):
+    from repro.configs import get_config
+    from repro.models import vit
+    cfg = get_config("deit-b")
+    shapes = jax.eval_shape(lambda: vit.init_params(cfg,
+                                                    jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), shapes)
+    imgs = _spec(one_chip, (8, cfg.img_res, cfg.img_res, 3))
+    fwd = jax.jit(lambda p, x: jnp.argmax(vit.forward(p, x, cfg), -1))
+    compiled = fwd.lower(params, imgs).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes > 150e6
