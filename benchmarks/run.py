@@ -35,8 +35,7 @@ def main() -> None:
     sections = []
 
     from benchmarks import fleetsim_bench, netsim_bench, orchestrator_bench, \
-        paper_tables, profile_report, queue_bench, roofline_report, \
-        serving_bench
+        paper_tables, queue_bench, roofline_report, serving_bench
     sections.append(("fig5_fig6", lambda: paper_tables.fig5_fig6(seeds)))
     sections.append(("ablations",
                      lambda: paper_tables.ablations(max(3, seeds // 2))))
@@ -54,10 +53,6 @@ def main() -> None:
         json_path=None if args.quick else netsim_bench.JSON_DEFAULT)))
     sections.append(("serving_engine", lambda: serving_bench.run(
         n_requests=30 if args.quick else 60)))
-    # full runs refresh the committed BENCH_profile.json attribution
-    sections.append(("scan_profile", lambda: profile_report.run(
-        smoke=args.quick,
-        json_path=None if args.quick else profile_report.JSON_DEFAULT)))
     if args.only == "roofline":     # needs a repro.launch.dryrun run first
         sections.append(("roofline", lambda: roofline_report.table(
             "results/dryrun_final")))

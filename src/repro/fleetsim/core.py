@@ -339,34 +339,38 @@ def _estep(state: EventState, _, *, topo: TopologyArrays, key, policy: str,
     # inflate the pending-work sum and flip verdicts.
     with jax.named_scope("fleetsim.retire"):
         state = _retire(state, t, R)
-    ps = p / topo.speeds                                    # (K,) scaled
-    cpu_free_c = jnp.maximum(t, state.busy[cur])
+    with jax.named_scope("fleetsim.decide"):
+        ps = p / topo.speeds                                # (K,) scaled
+        cpu_free_c = jnp.maximum(t, state.busy[cur])
 
     feas_all = j_all = cap_all = None
     if policy == "batched_feasible":
-        # (scope set inside the kernels.ops wrappers: "kernels.event_select")
         # the event_select kernel's slot in the step: the two-way merge and
         # the per-hop link_cost candidate mask fused into one pass over the
         # whole fleet's live windows.  The kernel re-derives the merge from
         # the same candidate scalars (bit-identical to the jnp merge above,
         # and load-bearing inside: the selected node picks which latency /
-        # inverse-bandwidth row the scoring reads).
-        w0_all = jnp.clip(state.head, 0, capacity - W)
-        win_all = lambda a: _row_windows(a, w0_all, W)
-        hrel_all = state.head - w0_all
+        # inverse-bandwidth row the scoring reads).  Both scorers run under
+        # "kernels.event_select" (the Pallas wrapper sets it itself), the
+        # window building before them under "fleetsim.windows".
+        with jax.named_scope("fleetsim.windows"):
+            w0_all = jnp.clip(state.head, 0, capacity - W)
+            win_all = lambda a: _row_windows(a, w0_all, W)
+            hrel_all = state.head - w0_all
+            wins = (win_all(state.starts), win_all(state.ends),
+                    win_all(state.sizes))
         lat, ibw = (net.latency, net.inv_bw) if use_network \
             else (zero_net, zero_net)
         if use_pallas:
             from repro.kernels import ops as kops
             sel = kops.event_select(
-                *cand_a, *cand_b, win_all(state.starts),
-                win_all(state.ends), win_all(state.sizes), state.nq,
-                hrel_all, topo.speeds, state.busy, lat, ibw)
+                *cand_a, *cand_b, *wins, state.nq, hrel_all, topo.speeds,
+                state.busy, lat, ibw)
         else:
-            sel = kref.event_select_ref(
-                *cand_a, *cand_b, win_all(state.starts),
-                win_all(state.ends), win_all(state.sizes), state.nq,
-                hrel_all, topo.speeds, state.busy, lat, ibw)
+            with jax.named_scope("kernels.event_select"):
+                sel = kref.event_select_ref(
+                    *cand_a, *cand_b, *wins, state.nq, hrel_all,
+                    topo.speeds, state.busy, lat, ibw)
         take_fresh, t, cur, feas_all, _, j_all, cap_all, _ = sel
 
     # -- admission test at the event's node -------------------------------
@@ -394,11 +398,12 @@ def _estep(state: EventState, _, *, topo: TopologyArrays, key, policy: str,
             ok, j, cap = okv[0], jv[0], capv[0]
 
     # -- decide: admit / forward / force / discard ------------------------
-    exhausted = (hops >= max_forwards) | (topo.degree[cur] == 0)
-    feas_evt = live & ok
-    forced_req = live & ~ok & exhausted & (not discard_on_exhaust)
-    disc_evt = live & ~ok & exhausted & discard_on_exhaust
-    fwd = live & ~ok & ~exhausted
+    with jax.named_scope("fleetsim.decide"):
+        exhausted = (hops >= max_forwards) | (topo.degree[cur] == 0)
+        feas_evt = live & ok
+        forced_req = live & ~ok & exhausted & (not discard_on_exhaust)
+        disc_evt = live & ~ok & exhausted & discard_on_exhaust
+        fwd = live & ~ok & ~exhausted
 
     # -- forward: pick the target NOW (true event time) and defer the
     # re-arrival to t + transfer_delay via a stable sorted insert ---------
@@ -446,9 +451,10 @@ def _estep(state: EventState, _, *, topo: TopologyArrays, key, policy: str,
     # idle CPU: the host engine pushes then immediately pops — net effect is
     # the request starts at its (wire-delayed) arrival and never enters
     # the ledger
-    start_now = admitted & idle
-    queue_it = admitted & ~idle
-    c_now = t + ps[cur]
+    with jax.named_scope("fleetsim.decide"):
+        start_now = admitted & idle
+        queue_it = admitted & ~idle
+        c_now = t + ps[cur]
 
     def put(buf, new, old):
         return jax.lax.dynamic_update_slice(
@@ -530,77 +536,85 @@ def _simulate(reqs: RequestArrays, topo: TopologyArrays, params: SimParams,
         raise ValueError("max_forwards must be < 256 (packed terminal "
                          f"record), got {max_forwards}")
     hop_bits = max(max_forwards + 1, 2).bit_length()
-    state = EventState(
-        starts=jnp.full((K, N), jq.BIG, dt),
-        ends=jnp.full((K, N), jq.BIG, dt),
-        sizes=jnp.zeros((K, N), dt),
-        slot_rid=jnp.zeros((K, N), jnp.int32),
-        head=jnp.zeros((K,), jnp.int32),
-        nq=jnp.zeros((K,), jnp.int32),
-        busy=jnp.zeros((K,), dt),
-        load=jnp.zeros((K,), dt),
-        rr=jnp.zeros((), jnp.int32),
-        cursor=jnp.zeros((), jnp.int32),
-        ev_time=jnp.full((B,), jq.BIG, dt),
-        ev_rid=jnp.zeros((B,), jnp.int32),
-        ev_meta=jnp.zeros((B,), jnp.int32),
-        ev_n=jnp.zeros((), jnp.int32),
-        ev_dropped=jnp.zeros((), jnp.int32),
-        sat_events=jnp.zeros((), jnp.int32),
-        completion=jnp.zeros((R,), dt),
-        reqinfo=jnp.zeros((R,), jnp.int32),
-        transfer=jnp.zeros((R,), dt),
-    )
-    tel_width = None
-    if tel_buckets is not None:
-        if tel_horizon is None:
-            raise ValueError("telemetry needs a horizon (TelemetryConfig "
-                             "carries both; got tel_buckets without "
-                             "tel_horizon)")
-        # the shared bucket contract (DESIGN.md §8): width computed ONCE
-        # in f32 on the host so both engines bin with bit-identical
-        # arithmetic
-        tel_width = jnp.asarray(bucket_width(tel_horizon, tel_buckets), dt)
-        tel_counts0, tel_occ0 = telemetry_init(K, tel_buckets)
-        state = state._replace(tel_counts=tel_counts0, tel_occ=tel_occ0)
-    key = jax.random.PRNGKey(params.seed)
-    d_abs = reqs.arrival + reqs.rel_deadline * params.sla_scale
-    payload = (reqs.payload if reqs.payload is not None
-               else jnp.zeros_like(reqs.arrival))
-    # per-request constants packed into row matrices: one gather per
-    # candidate per step instead of five (origin rides as f32 — exact for
-    # any node id below 2^24)
-    fresh_cols = jnp.stack([reqs.arrival, reqs.origin.astype(dt), d_abs,
-                            reqs.proc, payload], axis=1)
-    rear_cols = jnp.stack([d_abs, reqs.proc, payload], axis=1)
-    step = functools.partial(
-        _estep, topo=topo, key=key, policy=policy, max_forwards=max_forwards,
-        discard_on_exhaust=discard_on_exhaust, capacity=capacity,
-        depth=depth, use_pallas=use_pallas, R=R, use_network=use_network,
-        net=net, fresh_cols=fresh_cols, rear_cols=rear_cols,
-        targets=targets, zero_net=jnp.zeros((K, K), dt), hop_bits=hop_bits,
-        tel_buckets=tel_buckets, tel_width=tel_width)
-    state, _ = jax.lax.scan(step, state, None, length=E)
-    unprocessed = (R - state.cursor) + state.ev_n
-    state = _retire(state, jnp.asarray(jnp.inf, dt), R)     # drain
+    with jax.named_scope("fleetsim.pack"):
+        state = EventState(
+            starts=jnp.full((K, N), jq.BIG, dt),
+            ends=jnp.full((K, N), jq.BIG, dt),
+            sizes=jnp.zeros((K, N), dt),
+            slot_rid=jnp.zeros((K, N), jnp.int32),
+            head=jnp.zeros((K,), jnp.int32),
+            nq=jnp.zeros((K,), jnp.int32),
+            busy=jnp.zeros((K,), dt),
+            load=jnp.zeros((K,), dt),
+            rr=jnp.zeros((), jnp.int32),
+            cursor=jnp.zeros((), jnp.int32),
+            ev_time=jnp.full((B,), jq.BIG, dt),
+            ev_rid=jnp.zeros((B,), jnp.int32),
+            ev_meta=jnp.zeros((B,), jnp.int32),
+            ev_n=jnp.zeros((), jnp.int32),
+            ev_dropped=jnp.zeros((), jnp.int32),
+            sat_events=jnp.zeros((), jnp.int32),
+            completion=jnp.zeros((R,), dt),
+            reqinfo=jnp.zeros((R,), jnp.int32),
+            transfer=jnp.zeros((R,), dt),
+        )
+        tel_width = None
+        if tel_buckets is not None:
+            if tel_horizon is None:
+                raise ValueError("telemetry needs a horizon "
+                                 "(TelemetryConfig carries both; got "
+                                 "tel_buckets without tel_horizon)")
+            # the shared bucket contract (DESIGN.md §8): width computed
+            # ONCE in f32 on the host so both engines bin with
+            # bit-identical arithmetic
+            tel_width = jnp.asarray(bucket_width(tel_horizon, tel_buckets),
+                                    dt)
+            tel_counts0, tel_occ0 = telemetry_init(K, tel_buckets)
+            state = state._replace(tel_counts=tel_counts0, tel_occ=tel_occ0)
+        key = jax.random.PRNGKey(params.seed)
+        d_abs = reqs.arrival + reqs.rel_deadline * params.sla_scale
+        payload = (reqs.payload if reqs.payload is not None
+                   else jnp.zeros_like(reqs.arrival))
+        # per-request constants packed into row matrices: one gather per
+        # candidate per step instead of five (origin rides as f32 — exact
+        # for any node id below 2^24)
+        fresh_cols = jnp.stack([reqs.arrival, reqs.origin.astype(dt),
+                                d_abs, reqs.proc, payload], axis=1)
+        rear_cols = jnp.stack([d_abs, reqs.proc, payload], axis=1)
+        step = functools.partial(
+            _estep, topo=topo, key=key, policy=policy,
+            max_forwards=max_forwards, discard_on_exhaust=discard_on_exhaust,
+            capacity=capacity, depth=depth, use_pallas=use_pallas, R=R,
+            use_network=use_network, net=net, fresh_cols=fresh_cols,
+            rear_cols=rear_cols, targets=targets,
+            zero_net=jnp.zeros((K, K), dt), hop_bits=hop_bits,
+            tel_buckets=tel_buckets, tel_width=tel_width)
+    with jax.named_scope("fleetsim.scan"):
+        state, _ = jax.lax.scan(step, state, None, length=E)
+    with jax.named_scope("fleetsim.unpack"):
+        unprocessed = (R - state.cursor) + state.ev_n
+    with jax.named_scope("fleetsim.drain"):
+        state = _retire(state, jnp.asarray(jnp.inf, dt), R)
 
     # unpack the per-request terminal records
-    info = state.reqinfo
-    nfwd = info & ((1 << 8) - 1)
-    disc = (info & _INFO_DISC) != 0
-    ovf = (info & _INFO_OVF) != 0
-    served_by = (info >> _INFO_SERVED) - 1
-    completion = state.completion
-    has_c = completion > 0
-    met = has_c & (completion <= d_abs + _MET_EPS)
-    outcome = jnp.where(
-        disc, DISCARDED,
-        jnp.where(ovf, OVERFLOW,
-                  jnp.where(met, MET, jnp.where(has_c, LATE, PENDING))))
-    n_proc = jnp.sum(has_c)
-    resp = jnp.sum(jnp.where(has_c, completion - reqs.arrival, 0.0))
-    last_arrival = jnp.max(reqs.arrival, initial=0.0)
-    end_time = jnp.maximum(jnp.max(completion, initial=0.0), last_arrival)
+    with jax.named_scope("fleetsim.unpack"):
+        info = state.reqinfo
+        nfwd = info & ((1 << 8) - 1)
+        disc = (info & _INFO_DISC) != 0
+        ovf = (info & _INFO_OVF) != 0
+        served_by = (info >> _INFO_SERVED) - 1
+        completion = state.completion
+        has_c = completion > 0
+        met = has_c & (completion <= d_abs + _MET_EPS)
+        outcome = jnp.where(
+            disc, DISCARDED,
+            jnp.where(ovf, OVERFLOW,
+                      jnp.where(met, MET, jnp.where(has_c, LATE, PENDING))))
+        n_proc = jnp.sum(has_c)
+        resp = jnp.sum(jnp.where(has_c, completion - reqs.arrival, 0.0))
+        last_arrival = jnp.max(reqs.arrival, initial=0.0)
+        end_time = jnp.maximum(jnp.max(completion, initial=0.0),
+                               last_arrival)
     telemetry = None
     if tel_buckets is not None:
         # the derived half: queue depth and CPU busy time need no scan
@@ -624,25 +638,26 @@ def _simulate(reqs: RequestArrays, topo: TopologyArrays, params: SimParams,
                 busy_time=busy,
                 occupancy_hwm=state.tel_occ,
                 bucket_width=tel_width)
-    return FleetMetrics(
-        total=jnp.int32(R),
-        processed=n_proc.astype(jnp.int32),
-        met_deadline=jnp.sum(met).astype(jnp.int32),
-        forwards=jnp.sum(nfwd).astype(jnp.int32),
-        discarded=jnp.sum(disc).astype(jnp.int32),
-        overflow=jnp.sum(ovf).astype(jnp.int32),
-        window_saturation=state.sat_events,
-        mean_response_time=resp / jnp.maximum(1, n_proc),
-        end_time=end_time,
-        outcome=outcome,
-        completion=completion,
-        served_by=served_by,
-        forwards_used=nfwd,
-        transfer_time=jnp.sum(state.transfer),
-        transfer_used=state.transfer,
-        event_overflow=(state.ev_dropped + unprocessed).astype(jnp.int32),
-        telemetry=telemetry,
-    )
+    with jax.named_scope("fleetsim.unpack"):
+        return FleetMetrics(
+            total=jnp.int32(R),
+            processed=n_proc.astype(jnp.int32),
+            met_deadline=jnp.sum(met).astype(jnp.int32),
+            forwards=jnp.sum(nfwd).astype(jnp.int32),
+            discarded=jnp.sum(disc).astype(jnp.int32),
+            overflow=jnp.sum(ovf).astype(jnp.int32),
+            window_saturation=state.sat_events,
+            mean_response_time=resp / jnp.maximum(1, n_proc),
+            end_time=end_time,
+            outcome=outcome,
+            completion=completion,
+            served_by=served_by,
+            forwards_used=nfwd,
+            transfer_time=jnp.sum(state.transfer),
+            transfer_used=state.transfer,
+            event_overflow=(state.ev_dropped + unprocessed).astype(jnp.int32),
+            telemetry=telemetry,
+        )
 
 
 def simulate(reqs: RequestArrays, topo: TopologyArrays,

@@ -20,8 +20,11 @@ def test_serve_answers_every_request():
 @pytest.fixture
 def cache_config():
     was = jax.config.jax_compilation_cache_dir
+    was_meta = jax.config.jax_compilation_cache_include_metadata_in_key
     yield
     jax.config.update("jax_compilation_cache_dir", was)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      was_meta)
 
 
 def test_compile_cache_in_checkout(monkeypatch, cache_config):
@@ -40,3 +43,17 @@ def test_compile_cache_env_wins(monkeypatch, cache_config, tmp_path):
     before = jax.config.jax_compilation_cache_dir
     assert compile_cache.use_compile_cache() == str(tmp_path)
     assert jax.config.jax_compilation_cache_dir == before
+
+
+@pytest.mark.parametrize("env", [False, True])
+def test_compile_cache_keys_include_op_metadata(monkeypatch, cache_config,
+                                                tmp_path, env):
+    # the named scopes a trace reports live in the op metadata: an entry
+    # keyed without it would hand back an executable with stale scopes
+    if env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    compile_cache.use_compile_cache()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
