@@ -121,12 +121,8 @@ def bench_host_vs_fleet(wl, topology: Topology, link: LinkModel,
     reqs, _ = wl.to_arrays(seed, payload_fn=link.payload_of)
     net = link.net_params()
     R = len(requests)
-    # size the event plane off the host's realized forward count, with
-    # slack; event_overflow is asserted 0, so the sizing cannot silently
-    # clip the run
-    max_events = min(R * 3, R + 2 * host.forwards + 64)
     kw = dict(policy="least_loaded", capacity=capacity, depth=depth,
-              net=net, max_events=max_events)
+              net=net)
     # same seed both calls: the comparison must replay the same workload
     # cell, and the second call reuses the compiled executable
     cw = cold_warm(lambda: simulate(reqs, ta, SimParams.make(seed), **kw))
@@ -148,7 +144,7 @@ def bench_host_vs_fleet(wl, topology: Topology, link: LinkModel,
                  fidelity_node_mismatches=rep.node_mismatches,
                  host_transfer_time=round(host.transfer_time, 1),
                  host_forwards=host.forwards, fleet_forwards=int(m.forwards),
-                 max_events=max_events))
+                 scan_steps=int(m.scan_steps)))
 
 
 def run(smoke: bool = False,

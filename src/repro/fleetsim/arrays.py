@@ -22,9 +22,9 @@ Event tensors (DESIGN.md §7): the event-time scan advances over *events*
 a cursor, plus deferred re-arrivals in a compact sorted ``(event_buf,)``
 buffer of ``(time, rid, node, hops)`` columns.  Every request arrives at
 most ``max_forwards + 1`` times, so :func:`event_bound` — the static
-``R * (max_forwards + 1)`` worst case — bounds the scan length; callers
-may size ``max_events`` tighter (``R + expected forwards + slack``) and
-the scan surfaces any shortfall in ``metrics.event_overflow``.
+``R * (max_forwards + 1)`` worst case — caps the scan length; the scan
+itself stops at its last event, and surfaces any shortfall of a tighter
+``max_events`` in ``metrics.event_overflow``.
 """
 from __future__ import annotations
 
@@ -61,7 +61,7 @@ def event_bound(n_requests: int, max_forwards: int) -> int:
     """The static worst-case event count of a fleet run: every request is
     processed once per arrival, and a request re-arrives at most
     ``max_forwards`` times — ``R * (max_forwards + 1)`` scan steps cover
-    any forwarding realization (the default ``max_events``)."""
+    any forwarding realization (the default ``max_events``, a cap)."""
     return n_requests * (max_forwards + 1)
 
 

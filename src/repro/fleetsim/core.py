@@ -84,6 +84,10 @@ PENDING, MET, LATE, DISCARDED, OVERFLOW = 0, 1, 2, 3, 4
 
 _MET_EPS = 1e-9          # same slack as Request.met_deadline
 
+# steps per chunk of the event-time scan: it stops at the first chunk
+# boundary after its last event, so at most SCAN_CHUNK - 1 dead steps run
+SCAN_CHUNK = 128
+
 
 class SimParams(NamedTuple):
     """Traced sweep axes: everything here can carry a vmap dimension."""
@@ -162,6 +166,9 @@ class FleetMetrics(NamedTuple):
     transfer_used: jnp.ndarray       # (R,) per-request wire time
     event_overflow: jnp.ndarray      # events dropped (full buffer) or left
     #                                  unprocessed at max_events; keep 0
+    scan_steps: jnp.ndarray          # i32 steps the event scan ran: the
+    #                                  events rounded up to whole chunks
+    #                                  (SCAN_CHUNK), never above max_events
     telemetry: Optional[TelemetryFrame] = None   # the time-binned cube;
     #                                  None unless simulate(telemetry=...)
 
@@ -590,7 +597,27 @@ def _simulate(reqs: RequestArrays, topo: TopologyArrays, params: SimParams,
             zero_net=jnp.zeros((K, K), dt), hop_bits=hop_bits,
             tel_buckets=tel_buckets, tel_width=tel_width)
     with jax.named_scope("fleetsim.scan"):
-        state, _ = jax.lax.scan(step, state, None, length=E)
+        # E is a cap: the scan runs its E % SCAN_CHUNK odd steps, then
+        # whole chunks while an event is left, so it ends within a chunk
+        # of its last event.  Steps past it are dead (every flag False,
+        # the retire at +BIG is the drain's own pop chain) and the loop
+        # test runs once a chunk, which keeps a vmapped sweep's per-point
+        # carry selects off the step
+        def steps(s, n):
+            return jax.lax.scan(step, s, None, length=n)[0]
+
+        if E % SCAN_CHUNK:
+            state = steps(state, E % SCAN_CHUNK)
+
+        def events_left(c):
+            n, s = c
+            return (n < E) & ((s.cursor < R) | (s.ev_n > 0))
+
+        def chunk(c):
+            return c[0] + SCAN_CHUNK, steps(c[1], SCAN_CHUNK)
+
+        scan_steps, state = jax.lax.while_loop(
+            events_left, chunk, (jnp.int32(E % SCAN_CHUNK), state))
     with jax.named_scope("fleetsim.unpack"):
         unprocessed = (R - state.cursor) + state.ev_n
     with jax.named_scope("fleetsim.drain"):
@@ -656,6 +683,7 @@ def _simulate(reqs: RequestArrays, topo: TopologyArrays, params: SimParams,
             transfer_time=jnp.sum(state.transfer),
             transfer_used=state.transfer,
             event_overflow=(state.ev_dropped + unprocessed).astype(jnp.int32),
+            scan_steps=scan_steps,
             telemetry=telemetry,
         )
 
@@ -681,12 +709,13 @@ def simulate(reqs: RequestArrays, topo: TopologyArrays,
     size it at the node's total admission count, not its peak depth.
     ``depth`` (default ``capacity``) is the live-window width the per-step
     math runs over — size it at peak queue depth + slack; smaller depth =
-    faster steps.  ``max_events`` bounds the scan length (default
+    faster steps.  ``max_events`` caps the scan length (default
     ``R * (max_forwards + 1)``, the exact worst case — every request
-    forwarded to exhaustion; size it at ``R + expected forwards + slack``
-    for faster runs) and ``event_buf`` the in-flight re-arrival buffer
-    (default ``min(R, 1024)``).  Undersizing any of the four is never
-    silent: a forced push that finds no free slot is reported in
+    forwarded to exhaustion); the scan stops within ``SCAN_CHUNK`` steps
+    of its last event whatever the cap, and ``metrics.scan_steps`` says
+    how many it ran.  ``event_buf`` sizes the in-flight re-arrival
+    buffer (default ``min(R, 1024)``).  Undersizing any of the four is
+    never silent: a forced push that finds no free slot is reported in
     ``metrics.overflow``, a request that merely *consulted* a node with
     an exhausted window counts into ``metrics.window_saturation``, and a
     re-arrival that could not be buffered or processed counts into
@@ -779,10 +808,12 @@ def simulate_fn(*, policy: str = "random", max_forwards: int = 2,
         grid = jax.vmap(run, in_axes=(None, None, None, None, 0))
         metrics = grid(reqs, topo, params, tgt, stacked_net_params)
 
-    ``max_events``/``event_buf`` size the event-time scan (see
-    :func:`simulate`; defaults: the exact worst-case scan bound, and a
-    ``min(R, 1024)``-slot re-arrival buffer).  A sweep's sizing must
-    cover its heaviest cell — undersizing surfaces in
+    ``max_events``/``event_buf`` cap the event-time scan and size its
+    re-arrival buffer (see :func:`simulate`; defaults: the exact
+    worst-case scan bound, and a ``min(R, 1024)``-slot buffer).  Under
+    vmap the scan runs until the point with the most events is done, and
+    ``metrics.scan_steps`` holds each point's own count.  A sweep's
+    sizing must cover its heaviest cell — undersizing surfaces in
     ``metrics.event_overflow``, never silently, so check it across the
     whole sweep.
 

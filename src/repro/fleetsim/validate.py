@@ -186,11 +186,6 @@ def run_validation(scenario: str = "paper/scenario1", seed: int = 0,
     if capacity is None:
         capacity = 1 << max(3, (peak + 2 - 1).bit_length())
     window = 1 << max(3, (depth + 2 - 1).bit_length())
-    # scan length: one step per heap arrival event (fresh + re-arrivals),
-    # sized off the host's realized forward count with generous slack —
-    # event_overflow is asserted 0 below, so undersizing cannot pass
-    max_events = min(len(requests) * (max_forwards + 1),
-                     len(requests) + 2 * result.forwards + 256)
     reqs, _, _ = pack_requests(
         requests, payload_fn=network.payload_of if network else None)
     fleet_policy = policy if policy in DETERMINISTIC else "trace"
@@ -198,14 +193,12 @@ def run_validation(scenario: str = "paper/scenario1", seed: int = 0,
                        policy=fleet_policy, max_forwards=max_forwards,
                        discard_on_exhaust=discard_on_exhaust,
                        capacity=capacity, depth=window, targets=targets,
-                       net=network.net_params() if network else None,
-                       max_events=max_events)
+                       net=network.net_params() if network else None)
     assert int(m.overflow) == 0 and int(m.window_saturation) == 0, \
         f"fleet capacity {capacity}/depth {window} saturated " \
         f"(host peak admissions {peak}, depth {depth})"
     assert int(m.event_overflow) == 0, \
-        f"event plane saturated (max_events {max_events}, " \
-        f"host forwards {result.forwards})"
+        f"event plane saturated (host forwards {result.forwards})"
 
     agreement = None
     if telemetry is not None:
@@ -216,7 +209,6 @@ def run_validation(scenario: str = "paper/scenario1", seed: int = 0,
             discard_on_exhaust=discard_on_exhaust,
             capacity=capacity, depth=window, targets=targets,
             net=network.net_params() if network else None,
-            max_events=max_events,
             telemetry=TelemetryConfig(telemetry, horizon))
         # the disabled-path guarantee, measured from the enabled side:
         # carrying the cube must not perturb a single output bit

@@ -289,6 +289,7 @@ def test_undersized_event_plane_is_flagged_not_silent():
     # scan shorter than the event stream: leftovers are counted
     short = simulate(reqs, ta, SimParams.make(0), max_events=R // 2, **kw)
     assert int(short.event_overflow) > 0
+    assert int(short.scan_steps) == R // 2        # the cap ran out
     # a 1-slot buffer under a priced network (25 UT wire vs ~3.6 UT
     # arrival gaps => many referrals in flight at once): drops counted
     net = NetParams.uniform(3, 25.0)
@@ -296,6 +297,97 @@ def test_undersized_event_plane_is_flagged_not_silent():
     assert int(tight.event_overflow) > 0
     sized = simulate(reqs, ta, SimParams.make(0), net=net, **kw)
     assert int(sized.event_overflow) == 0
+
+
+# ---------------------------------------------------------------------------
+# the event scan ends at its last event (max_events is a cap)
+# ---------------------------------------------------------------------------
+_SCAN_FIELDS = ("outcome", "served_by", "completion", "transfer_used",
+                "forwards_used")
+_SWEEP_SLA = (0.5, 1.0, 1.5, 2.0)
+
+
+def _scan_case(case: str, net: bool, max_events=None):
+    """One run of the hot fleet (``case`` a policy, or ``sweep``: four
+    vmapped round-robin points, one stream and SLA scale each) with
+    telemetry on.  The priced wire is long enough that re-arrivals go on
+    for more than a chunk after the last fresh arrival."""
+    from repro.fleetsim import NetParams
+    from repro.telemetry import TelemetryConfig
+    ta = topology_arrays(Topology.full_mesh(3))
+    kw = dict(capacity=256, depth=128, max_events=max_events,
+              telemetry=TelemetryConfig(16, 12000.0))
+    wire = NetParams.uniform(3, 400.0) if net else None
+    if case != "sweep":
+        reqs, _, _ = pack_requests(HOT.generate(0))
+        return simulate(reqs, ta, SimParams.make(0, 0.5), policy=case,
+                        net=wire, **kw)
+    streams = [pack_requests(HOT.generate(s))[0] for s in range(4)]
+    reqs = type(streams[0])(*(jnp.stack([jnp.asarray(r[i]) for r in streams])
+                              for i in range(6)))
+    R = reqs.arrival.shape[1]
+    run = simulate_fn(policy="round_robin", network=net, **kw)
+    args = (reqs, ta, SimParams(jnp.zeros(4, jnp.int32),
+                                jnp.asarray(_SWEEP_SLA, jnp.float32)),
+            jnp.full((R, 2), -1, jnp.int32))
+    axes = (0, None, SimParams(0, 0), None)
+    if net:
+        args, axes = args + (wire,), axes + (None,)
+    return jax.vmap(run, in_axes=axes)(*args)
+
+
+def _chunked(events, E):
+    """Steps the scan runs: E % SCAN_CHUNK odd steps, then whole chunks
+    until no event is left, never more than E."""
+    from repro.fleetsim.core import SCAN_CHUNK as C
+    head = E % C
+    return np.minimum(E, head + -(-np.maximum(events - head, 0) // C) * C)
+
+
+@pytest.mark.parametrize("net", [False, True], ids=["no_net", "net"])
+@pytest.mark.parametrize("case", ["batched_feasible", "round_robin",
+                                  "sweep"])
+def test_early_exit_scan_matches_exact_length_scan(case, net):
+    """Stopping at the last event changes nothing: against a scan sized
+    at exactly the realized events (R + forwards), every per-request
+    output and the telemetry frame are bit-identical."""
+    m = _scan_case(case, net)
+    R = m.outcome.shape[-1]
+    fwd = np.asarray(m.forwards)
+    assert int(np.max(m.event_overflow)) == 0 and fwd.max() > 0
+    exact = _scan_case(case, net, max_events=R + int(fwd.max()))
+    assert int(np.max(exact.event_overflow)) == 0
+    for f in _SCAN_FIELDS:
+        np.testing.assert_array_equal(np.asarray(getattr(m, f)),
+                                      np.asarray(getattr(exact, f)), f)
+    for a, b in zip(m.telemetry, exact.telemetry):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("case", ["batched_feasible", "round_robin",
+                                  "sweep"])
+def test_scan_steps_count_whole_chunks(case):
+    """``scan_steps`` is the realized events (each point's own under
+    vmap) rounded up to whole chunks: at least the events, within a chunk
+    of them, and never above the cap."""
+    from repro.fleetsim.core import SCAN_CHUNK
+    m = _scan_case(case, net=True)
+    assert int(np.max(m.event_overflow)) == 0
+    R = m.outcome.shape[-1]
+    E = 3 * R
+    events = R + np.asarray(m.forwards)
+    steps = np.asarray(m.scan_steps)
+    assert steps.shape == events.shape
+    assert (events <= steps).all() and (steps <= E).all()
+    assert (steps < events + SCAN_CHUNK).all()
+    np.testing.assert_array_equal(steps, _chunked(events, E))
+    if case == "sweep":     # the points end at different events
+        assert len(set(steps.tolist())) > 1
+    # capped at the (largest) event count, the cap ends the last chunk
+    cap = int(events.max())
+    exact = _scan_case(case, True, max_events=cap)
+    np.testing.assert_array_equal(np.asarray(exact.scan_steps),
+                                  _chunked(events, cap))
 
 
 def test_event_scan_orders_rearrivals_by_time_not_source():

@@ -6,8 +6,9 @@ op to the innermost such name in its name stack and puts an op outside
 every scope in the row ``unscoped``.  These tests compile ``_simulate`` at
 a small fleet, for the CPU and for a described v5e chip, and map every
 top-level instruction of the entry computation and of every loop
-computation (the scan step, the retire and drain loops, loops the
-compiler builds) through the same ``op_scope``.
+computation (the scan's chunk loop and the step loop inside it, the
+retire and drain loops, loops the compiler builds) through the same
+``op_scope``.
 
 Instructions with no name of their own are skipped, and their opcodes
 are named in ``UNNAMED``: XLA creates them (carry copies and their async
@@ -134,16 +135,38 @@ def _compile(case: str, sharding):
     return lowered.compile().as_text()
 
 
+def loop_tree(hlo: str):
+    """Every loop of the entry computation as ``(scope, body_scopes,
+    loops)``: the loop's own scope, the scopes of its body's named ops,
+    and the loops of its body in the same form."""
+    comps, entry = _computations(hlo)
+
+    def loops(c):
+        out = []
+        for line in comps[c]:
+            if not re.search(r"\swhile\(", line):
+                continue
+            body = re.search(r"body=%?([\w.\-]+)", line).group(1)
+            names = [_OP_NAME.search(ln) for ln in comps[body]]
+            out.append((op_scope({"op_name": _OP_NAME.search(line).group(1)}),
+                        {op_scope({"op_name": m.group(1)}) for m in names
+                         if m and not unnamed(m.group(1))},
+                        loops(body)))
+        return out
+    return loops(entry)
+
+
 @pytest.fixture(scope="module")
 def compiled(request):
     cache = {}
 
-    def get(target, case):
+    def get(target, case, tree=False):
         if (target, case) not in cache:
             sharding = (request.getfixturevalue("one_chip")
                         if target == "v5e" else None)
-            cache[target, case] = top_level(_compile(case, sharding))
-        return cache[target, case]
+            hlo = _compile(case, sharding)
+            cache[target, case] = (top_level(hlo), loop_tree(hlo))
+        return cache[target, case][int(tree)]
     return get
 
 
@@ -183,3 +206,26 @@ def test_scan_and_drain_loops_are_scoped(compiled, target, case):
              if c == entry and opcode == "while"}
     assert {"fleetsim.scan", "fleetsim.drain"} <= loops
     assert loops <= {"fleetsim.scan", "fleetsim.drain", "fleetsim.retire"}
+
+
+@pytest.mark.parametrize("target", ["cpu", "v5e"])
+@pytest.mark.parametrize("case", ["batched_feasible", "round_robin",
+                                  "sweep"])
+def test_scan_chunks_nest_the_step_loop(compiled, target, case):
+    """The scan runs its steps in chunks: a ``fleetsim.scan`` loop whose
+    body is the chunk's own ``fleetsim.scan`` loop over the step, with the
+    retire loop inside that.  The outer body holds the loop test, the
+    carry selects and what the compiler hoists out of the step; the
+    step's phases sit one loop down."""
+    outer = [lp for lp in compiled(target, case, tree=True)
+             if lp[0] == "fleetsim.scan"
+             and any(c[0] == "fleetsim.scan" for c in lp[2])]
+    assert len(outer) == 1
+    _, scopes, inner = outer[0]
+    step = {"fleetsim.event_pop", "fleetsim.route", "fleetsim.admission",
+            "fleetsim.scatter"}
+    assert "fleetsim.scan" in scopes and not scopes & step, scopes
+    assert [lp[0] for lp in inner] == ["fleetsim.scan"]
+    _, step_scopes, step_loops = inner[0]
+    assert step <= step_scopes
+    assert "fleetsim.retire" in {lp[0] for lp in step_loops}

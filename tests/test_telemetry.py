@@ -86,8 +86,9 @@ def test_disabled_adds_no_scan_carries():
                 first = eqn.params["num_consts"]
                 return [(v.aval.shape, v.aval.dtype) for v in
                         eqn.invars[first:first + eqn.params["num_carry"]]]
-            if "jaxpr" in eqn.params:           # descend through pjit
-                eqns = list(eqn.params["jaxpr"].jaxpr.eqns) + eqns
+            for sub in ("jaxpr", "body_jaxpr"):  # through pjit, while
+                if sub in eqn.params:
+                    eqns = list(eqn.params[sub].jaxpr.eqns) + eqns
         raise AssertionError("no scan found in jaxpr")
 
     K, NB = 3, 16
