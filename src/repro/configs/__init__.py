@@ -9,7 +9,7 @@ import importlib
 from typing import Dict, List
 
 from repro.configs.base import (DiTConfig, LMConfig, ResNetConfig, UNetConfig,
-                                ViTConfig)
+                                ViTConfig, VLMConfig)
 from repro.configs.shapes import (FAMILY_SHAPES, ShapeSpec, cell_is_applicable,
                                   shapes_for)
 
@@ -24,6 +24,7 @@ _MODULES: Dict[str, str] = {
     "vit-h14": "vit_h14",
     "deit-b": "deit_b",
     "resnet-50": "resnet50",
+    "kimi-vl-a3b": "kimi_vl_a3b",
 }
 
 ARCHS: List[str] = list(_MODULES)
@@ -59,4 +60,5 @@ def all_cells():
 
 __all__ = ["ARCHS", "get_config", "get_smoke_config", "all_cells",
            "shapes_for", "cell_is_applicable", "ShapeSpec", "FAMILY_SHAPES",
-           "LMConfig", "ViTConfig", "ResNetConfig", "DiTConfig", "UNetConfig"]
+           "LMConfig", "ViTConfig", "ResNetConfig", "DiTConfig", "UNetConfig",
+           "VLMConfig"]

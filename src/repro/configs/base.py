@@ -43,10 +43,39 @@ class LMConfig:
     # expert count (granite's 40) expert-shardable over the 16-way model axis
     # (dummy experts are masked out of routing; §Perf iteration A3)
     n_experts_pad: int = 0
+    # multi-head latent attention (DeepSeek-V2/V3; kv_lora_rank 0 = GQA):
+    # keys and values come from a kv_lora_rank latent; q and k carry
+    # qk_nope_head_dim channels without and qk_rope_head_dim with RoPE
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # leading dense layers of width dense_d_ff, a stack of their own
+    # (DeepSeek's first_k_dense_replace); the MoE stack follows
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
+    # router: softmax | sigmoid (noaux_tc: a per-expert bias picks the
+    # top-k, the unbiased scores weigh them, normalized, times routed_scale;
+    # no token is dropped)
+    router: str = "softmax"
+    routed_scale: float = 1.0
+    # experts [lo, hi) of n_experts held by this chip (expert parallelism);
+    # routing is over all n_experts, the layer computes its own experts' part
+    held_experts: Optional[Tuple[int, int]] = None
+    embed_scale: bool = True       # embeddings times sqrt(d_model) (Gemma)
+    norm_eps: float = 1e-6         # RMSNorm epsilon of the latent-attention path
 
     @property
     def n_experts_eff(self) -> int:
         return max(self.n_experts, self.n_experts_pad)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def experts_here(self) -> Tuple[int, int]:
+        return self.held_experts or (0, self.n_experts)
     # ZeRO: additionally shard weights/opt-state over the pod axis (needed by
     # trillion-parameter configs to fit v5e HBM; see DESIGN.md §5)
     zero_over_pods: bool = False
@@ -108,18 +137,27 @@ class ViTConfig:
     d_model: int
     n_heads: int
     d_ff: int
-    n_classes: int = 1000
+    n_classes: int = 1000           # 0: no head, every patch token out
+    class_token: bool = True
     distill_token: bool = False     # DeiT
     in_channels: int = 3
+    # 2-D RoPE on q and k (MoonViT): half of each head's rotary pairs turn
+    # with the patch's column, half with its row
+    rope_2d: bool = False
+    pos_interp: str = "bilinear"    # resizing the learned position table
     param_dtype: str = "bfloat16"
     remat: bool = True
     attn_impl: str = "chunked"
     attn_chunk: int = 512
     family: str = "vit"
 
+    @property
+    def n_extra(self) -> int:
+        return int(self.class_token) + int(self.distill_token)
+
     def n_tokens(self, img_res: Optional[int] = None) -> int:
         r = img_res or self.img_res
-        return (r // self.patch) ** 2 + 1 + int(self.distill_token)
+        return (r // self.patch) ** 2 + self.n_extra
 
     def total_params(self) -> int:
         d = self.d_model
@@ -195,6 +233,26 @@ class UNetConfig:
 
     def total_params(self) -> int:
         return 860_000_000  # nominal SD1.5 UNet
+
+
+@dataclasses.dataclass(frozen=True)
+class VLMConfig:
+    """Vision-language model: a ViT tower, a projector that merges
+    ``merge`` x ``merge`` patches into one LM token, and a decoder LM that
+    answers ``answer_len`` greedy tokens about a ``frame_hw`` frame."""
+    name: str
+    vision: ViTConfig
+    lm: LMConfig
+    merge: int = 2
+    frame_hw: Tuple[int, int] = (504, 896)
+    answer_len: int = 32
+    family: str = "vlm"
+
+    @property
+    def image_tokens(self) -> int:
+        h, w = self.frame_hw
+        p = self.vision.patch * self.merge
+        return (h // p) * (w // p)
 
 
 ArchConfig = object  # union marker; families dispatch on .family

@@ -49,6 +49,8 @@ FAMILY_SHAPES = {
     "resnet": VISION_SHAPES,
     "dit": DIFFUSION_SHAPES,
     "unet": DIFFUSION_SHAPES,
+    # served through launch/serve.py and the benchmark, not the dry-run grid
+    "vlm": {},
 }
 
 

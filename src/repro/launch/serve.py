@@ -10,13 +10,15 @@ between replicas on rejection, and executed in deadline-aware batches.
 Without ``--full`` the registry's reduced smoke configuration runs (CPU
 friendly); ``--full`` serves the published configuration.  :func:`serve`
 is the same path as a function, for callers that need the per-request
-results (``chip_smoke.py``).
+results (``chip_smoke.py``).  A vision-language model (``--arch
+kimi-vl-a3b``) answers a random prompt about each frame, through the
+``run_batch`` of ``repro.models.kimi_vl.Runner``.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
-from typing import Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +29,8 @@ import numpy as np
 class ServeReport:
     """What one :func:`serve` run answered."""
     stats: Dict[str, int]             # DeadlineAwareEngine.stats()
-    results: List[Optional[int]]      # per-request argmax class, by rid
+    # per-request argmax class (vision) or answer ids (VLM), by rid
+    results: List[Optional[Any]]
     devices: List[str]                # the device each replica ran on
 
     @property
@@ -80,9 +83,9 @@ def serve(arch: str = "deit-b", *, full: bool = False, replicas: int = 3,
                                       ServingReplica)
 
     cfg = get_config(arch) if full else get_smoke_config(arch)
-    if cfg.family not in ("vit", "resnet"):
-        raise SystemExit("serve launcher supports vision archs; "
-                         "see examples/ for LM decode serving")
+    if cfg.family not in ("vit", "resnet", "vlm"):
+        raise SystemExit("serve launcher supports vision and vision-language "
+                         "archs; see examples/ for LM decode serving")
     mod = model_module(cfg)
     # one compiled init: op by op, each parameter shape compiles its own
     # small programs, which on the TPU costs more than the forward
@@ -91,10 +94,28 @@ def serve(arch: str = "deit-b", *, full: bool = False, replicas: int = 3,
     devices = list(devices) if devices else [jax.devices()[0]]
     runners = {}
     for dev in devices:
-        if dev not in runners:
+        if dev in runners:
+            continue
+        if cfg.family == "vlm":
+            from repro.models import kimi_vl
+            runners[dev] = kimi_vl.Runner(jax.device_put(params, dev), cfg,
+                                          max_batch)
+        else:
             runners[dev] = _replica_runner(mod, cfg, params, dev, max_batch)
 
-    cls = ServiceClass("hd", cfg.img_res, deadline=deadline, proc_time=4.0)
+    rng = np.random.default_rng(0)
+    if cfg.family == "vlm":
+        from repro.models import kimi_vl
+        h, w = cfg.frame_hw
+        payloads = [kimi_vl.Request(
+            rng.standard_normal((h, w, 3), np.float32),
+            rng.integers(0, cfg.lm.vocab_size, int(rng.integers(1, 129)),
+                         dtype=np.int32)) for _ in range(requests)]
+    else:
+        h = cfg.img_res
+        payloads = list(rng.standard_normal(
+            (requests, cfg.img_res, cfg.img_res, 3), np.float32))
+    cls = ServiceClass("hd", h, deadline=deadline, proc_time=4.0)
     cls.batch_proc_time = {1: 4.0, 2: 4.6, 4: 5.8, 8: 8.0}
     reps = []
     for i in range(replicas):
@@ -103,14 +124,15 @@ def serve(arch: str = "deit-b", *, full: bool = False, replicas: int = 3,
                                    queue=q, max_batch=max_batch))
     eng = DeadlineAwareEngine(reps)
 
-    rng = np.random.default_rng(0)
-    frames = rng.standard_normal(
-        (requests, cfg.img_res, cfg.img_res, 3), np.float32)
     arrivals = np.cumsum(rng.exponential(inter_arrival, size=requests))
-    reqs = [eng.submit(frames[i], cls, now=float(at), origin=i % replicas)
+    reqs = [eng.submit(payloads[i], cls, now=float(at), origin=i % replicas)
             for i, at in enumerate(arrivals)]
     eng.drain(float(arrivals[-1]))
-    return ServeReport(stats=eng.stats(), results=[r.result for r in reqs],
+    results = [r.result for r in reqs]
+    if cfg.family == "vlm":
+        results = [None if a is None else tuple(int(t) for t in a.ids)
+                   for a in results]
+    return ServeReport(stats=eng.stats(), results=results,
                        devices=[str(devices[i % len(devices)])
                                 for i in range(replicas)])
 

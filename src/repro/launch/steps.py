@@ -20,9 +20,9 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, shapes_for
 from repro.configs.base import (DiTConfig, LMConfig, ResNetConfig, UNetConfig,
-                                ViTConfig)
+                                ViTConfig, VLMConfig)
 from repro.configs.shapes import ShapeSpec
-from repro.models import common, dit, resnet, transformer, unet, vit
+from repro.models import common, dit, kimi_vl, resnet, transformer, unet, vit
 from repro.training.optimizer import AdamWConfig, OptState, init_opt_state, \
     opt_state_specs
 
@@ -31,7 +31,7 @@ PyTree = Any
 
 def model_module(cfg):
     return {"lm": transformer, "vit": vit, "resnet": resnet,
-            "dit": dit, "unet": unet}[cfg.family]
+            "dit": dit, "unet": unet, "vlm": kimi_vl}[cfg.family]
 
 
 def _nest_logical(flat: Dict[str, Tuple]) -> PyTree:
@@ -89,6 +89,15 @@ def _vision_batch_specs(cfg, shape: ShapeSpec):
             "labels": jax.ShapeDtypeStruct((B,), jnp.int32)}
 
 
+def _vlm_batch_specs(cfg: VLMConfig, shape: ShapeSpec):
+    """Frames at the config's size and prompts of ``seq_len`` tokens (one
+    prompt block by default)."""
+    B, S = shape.global_batch, shape.seq_len or kimi_vl.PROMPT_BLOCK
+    return {"images": jax.ShapeDtypeStruct((B, *cfg.frame_hw, 3), jnp.float32),
+            "tokens": jax.ShapeDtypeStruct((B, S), jnp.int32),
+            "labels": jax.ShapeDtypeStruct((B, S), jnp.int32)}
+
+
 def _dit_batch_specs(cfg: DiTConfig, shape: ShapeSpec):
     B = shape.global_batch
     lr = cfg.latent_res(shape.img_res)
@@ -140,6 +149,8 @@ def build_cell(arch: str, shape_name: str, cfg=None) -> Cell:
             b_specs = _lm_batch_specs(cfg, shape)
         elif cfg.family in ("vit", "resnet"):
             b_specs = _vision_batch_specs(cfg, shape)
+        elif cfg.family == "vlm":
+            b_specs = _vlm_batch_specs(cfg, shape)
         elif cfg.family == "dit":
             b_specs = _dit_batch_specs(cfg, shape)
         else:
@@ -221,6 +232,23 @@ def build_cell(arch: str, shape_name: str, cfg=None) -> Cell:
 
         return Cell(arch, shape, cfg, step, (p_specs, i_spec),
                     (p_logical, ("dp", None, None, None)), make_args)
+
+    if cfg.family == "vlm":
+        specs = _vlm_batch_specs(cfg, shape)
+        f_spec, t_spec = specs["images"], specs["tokens"]
+
+        def step(params, frames, tokens):
+            return kimi_vl.serve_step(params, frames, tokens, cfg)
+
+        def make_args(key):
+            return (mod.init_params(cfg, key),
+                    jax.random.normal(key, f_spec.shape, jnp.float32),
+                    jax.random.randint(key, t_spec.shape, 0,
+                                       cfg.lm.vocab_size).astype(jnp.int32))
+
+        return Cell(arch, shape, cfg, step, (p_specs, f_spec, t_spec),
+                    (p_logical, ("dp", None, None, None), ("dp", None)),
+                    make_args)
 
     if cfg.family == "dit":
         B = shape.global_batch

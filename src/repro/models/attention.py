@@ -84,7 +84,7 @@ def attention_naive(q, k, v, *, causal=True, window=None,
     scores = scores + _mask_bias(q_pos, k_pos, causal, window)[None, None, None]
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
-    return out.reshape(B, Sq, H, D)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 def attention_chunked(q, k, v, *, causal=True, window=None,

@@ -6,6 +6,10 @@ grouped einsum on the MXU — the buffer's expert axis shards over the
 ``model`` mesh axis (expert parallelism) and GSPMD turns the gather/scatter
 into the canonical MoE all-to-alls.  Tokens over capacity are dropped
 (GShard-style); the residual stream carries them unchanged.
+
+:func:`moe_held` is the drop-free DeepSeek-V3 layer for a chip of an
+expert-parallel group: sigmoid routing over every expert, and a grouped
+SwiGLU (:func:`grouped_swiglu`) of only the experts this chip holds.
 """
 from __future__ import annotations
 
@@ -217,3 +221,167 @@ def moe_ffn(x: jnp.ndarray, router_w: jnp.ndarray, w_gate: jnp.ndarray,
         keep, flat_gates, 0.0)[:, None]
     out = jnp.zeros((T, d), jnp.float32).at[tok].add(weighted)
     return out.astype(x.dtype), aux
+
+
+def route_sigmoid(x: jnp.ndarray, router_w: jnp.ndarray, bias: jnp.ndarray,
+                  top_k: int, scale: float
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """DeepSeek-V3 ``noaux_tc`` routing in float32: sigmoid scores, the
+    per-expert correction ``bias`` added only to pick the top-k, the picked
+    unbiased scores normalized to sum 1 and times ``scale``.
+
+    x (T, d), router_w (d, E), bias (E,) -> (gates (T, K) f32, experts
+    (T, K) int32)."""
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                        router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    gates = jnp.take_along_axis(scores, experts, axis=-1)
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True) * scale
+    return gates, experts.astype(jnp.int32)
+
+
+def _plan(M: int, sizes: jnp.ndarray):
+    """The (row tile, group) pairs of :func:`grouped_swiglu`: each tile of
+    ``tm`` rows is visited once for each group that owns rows in it."""
+    tm = min(256, -(-M // 8) * 8)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tm
+    n_tiles = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    done = jnp.cumsum(n_tiles)                       # tiles up to group g
+
+    def pair(i):
+        """Visit ``i`` -> (group, tile, rows of the tile the group owns)."""
+        g = jnp.sum(done <= i)
+        t = first[g] + i - (done[g] - n_tiles[g])
+        r = t * tm + jnp.arange(tm)
+        return g, t, (r >= starts[g]) & (r < ends[g])
+
+    return tm, -(-M // tm) * tm, done[-1], pair
+
+
+def _expert(w: jnp.ndarray, layer, g) -> jnp.ndarray:
+    """Expert ``g`` of layer ``layer`` of a stack (L, E, a, b)."""
+    return jax.lax.dynamic_slice(
+        w, (layer, g, 0, 0), (1, 1) + w.shape[2:]).reshape(w.shape[2:])
+
+
+def _swiglu_expert(x, w_gate, w_up, w_down):
+    return swiglu(x @ w_gate, x @ w_up) @ w_down
+
+
+@jax.custom_vjp
+def grouped_swiglu(rows: jnp.ndarray, sizes: jnp.ndarray,
+                   w_gate: jnp.ndarray, w_up: jnp.ndarray,
+                   w_down: jnp.ndarray, layer) -> jnp.ndarray:
+    """Each row of a group through that group's SwiGLU expert of layer
+    ``layer``.
+
+    rows (M, d), sorted by group: group g owns ``sizes[g]`` rows after
+    those of the groups before it; rows past ``sum(sizes)`` come out zero.
+    The weights are stacks (L, E, d, f) / (L, E, f, d).  A loop visits
+    each (row tile, group) pair that holds rows of the group, so empty
+    groups and rows past the last group compute nothing, and each visited
+    expert's weights are read from the stack once per tile, in place."""
+    return _grouped_fwd(rows, sizes, w_gate, w_up, w_down, layer)[0]
+
+
+def _grouped_fwd(rows, sizes, w_gate, w_up, w_down, layer):
+    M, d = rows.shape
+    tm, Mp, n_visits, pair = _plan(M, sizes)
+    xs = jnp.pad(rows, ((0, Mp - M), (0, 0)))
+
+    def visit(i, out):
+        g, t, mine = pair(i)
+        x = jax.lax.dynamic_slice_in_dim(xs, t * tm, tm)
+        h = _swiglu_expert(x, _expert(w_gate, layer, g),
+                           _expert(w_up, layer, g), _expert(w_down, layer, g))
+        cur = jax.lax.dynamic_slice_in_dim(out, t * tm, tm)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.where(mine[:, None], h, cur), t * tm, 0)
+
+    out = jax.lax.fori_loop(0, n_visits, visit, jnp.zeros_like(xs))
+    return out[:M], (rows, sizes, w_gate, w_up, w_down, layer)
+
+
+def _grouped_bwd(res, dy):
+    """The same visits, each through the expert's VJP: a row's gradient
+    comes from its own group's visit, an expert's from its rows."""
+    rows, sizes, w_gate, w_up, w_down, layer = res
+    M, d = rows.shape
+    tm, Mp, n_visits, pair = _plan(M, sizes)
+    xs = jnp.pad(rows, ((0, Mp - M), (0, 0)))
+    dys = jnp.pad(dy.astype(rows.dtype), ((0, Mp - M), (0, 0)))
+
+    def visit(i, acc):
+        dx, dws = acc
+        g, t, mine = pair(i)
+        x = jax.lax.dynamic_slice_in_dim(xs, t * tm, tm)
+        dyt = jnp.where(mine[:, None],
+                        jax.lax.dynamic_slice_in_dim(dys, t * tm, tm), 0)
+        ws = [_expert(w, layer, g) for w in (w_gate, w_up, w_down)]
+        _, vjp = jax.vjp(_swiglu_expert, x, *ws)
+        gx, *gws = vjp(dyt)
+        cur = jax.lax.dynamic_slice_in_dim(dx, t * tm, tm)
+        dx = jax.lax.dynamic_update_slice_in_dim(
+            dx, jnp.where(mine[:, None], gx, cur), t * tm, 0)
+        dws = tuple(
+            jax.lax.dynamic_update_slice(
+                dw, (_expert(dw, layer, g) + gw)[None, None],
+                (layer, g, 0, 0))
+            for dw, gw in zip(dws, gws))
+        return dx, dws
+
+    dx, dws = jax.lax.fori_loop(
+        0, n_visits, visit,
+        (jnp.zeros_like(xs), tuple(jnp.zeros_like(w)
+                                   for w in (w_gate, w_up, w_down))))
+    return (dx[:M], None, *dws, None)
+
+
+grouped_swiglu.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def moe_held(x: jnp.ndarray, valid: jnp.ndarray, router_w: jnp.ndarray,
+             bias: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
+             w_down: jnp.ndarray, *, top_k: int, scale: float, first: int,
+             layer=None) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The part of a drop-free sigmoid-routed MoE layer that the experts
+    ``first .. first + E_held`` give.
+
+    x (T, d), routed as given (float32 in the DeepSeek-V3 layer) and
+    computed in the experts' dtype; ``valid`` (T,) bool, False for
+    padding, which routes nowhere; expert weights (E_held, d, f) /
+    (E_held, f, d), or with ``layer`` the stacks (L, E_held, d, f) /
+    (L, E_held, f, d) of which layer ``layer`` is read.  Every (token,
+    held expert) route is computed: the routes are sorted by expert and go
+    through :func:`grouped_swiglu`.  Returns ``(out (T, d) float32,
+    routed, experts)``, where ``routed`` (2,) int32 counts the (token,
+    held expert) routes and the held experts given at least one, and
+    ``experts`` (T, K) int32 are the picks among all experts."""
+    if layer is None:
+        w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
+        layer = 0
+    T, d = x.shape
+    E_held = w_gate.shape[1]
+    with jax.named_scope("kernels.moe_route"):
+        gates, experts = route_sigmoid(x, router_w, bias, top_k, scale)
+        local = experts - first
+        mine = (local >= 0) & (local < E_held) & valid[:, None]    # (T, K)
+        group = jnp.where(mine, local, E_held).reshape(-1)         # (T*K,)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=E_held + 1)[:E_held].astype(
+            jnp.int32)
+        rows = x[order // top_k].astype(w_gate.dtype)              # (T*K, d)
+    with jax.named_scope("kernels.moe_experts"):
+        y = grouped_swiglu(rows, sizes, w_gate, w_up, w_down,
+                           jnp.asarray(layer, jnp.int32))
+    with jax.named_scope("kernels.moe_route"):
+        y = y[jnp.argsort(order)].reshape(T, top_k, d)             # unsort
+        y = jnp.where(mine[..., None], y.astype(jnp.float32), 0.0)
+        out = jnp.sum(y * gates[..., None], axis=1)
+        routed = jnp.stack([jnp.sum(mine), jnp.sum(sizes > 0)]).astype(
+            jnp.int32)
+    return out, routed, experts

@@ -11,6 +11,12 @@ Step functions:
 * ``decode_step_sliding`` — gemma3 path: ring-buffer window caches for local
   layers, full caches only for the 1-in-6 global layers (the sub-quadratic
   structure that makes ``long_500k`` feasible).
+
+Latent-attention configs (``cfg.mla``, Kimi-VL's DeepSeek-V3 block) keep
+their ``first_k_dense`` leading dense layers in a stack of their own
+(``dense_layers``) before the MoE stack (``layers``), take input
+embeddings in ``prefill`` (a VLM's image tokens), and cache one latent a
+token (``models/mla.py``) with a length per row.
 """
 from __future__ import annotations
 
@@ -36,41 +42,69 @@ def _dtype(cfg: LMConfig):
     return jnp.dtype(cfg.param_dtype)
 
 
+def _stack_defs(stack: str, L: int, cfg: LMConfig, moe_ffn: bool,
+                f: int) -> Dict[str, common.ParamDef]:
+    """One stack of ``L`` layers: norms, attention, and an MoE or dense FFN
+    of width ``f``."""
+    d, H, KV, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    dt = _dtype(cfg)
+    defs = {"ln1": common.ParamDef((L, d), "zeros", dtype=dt),
+            "ln2": common.ParamDef((L, d), "zeros", dtype=dt)}
+    if cfg.mla:
+        r, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        defs.update({
+            "wq": common.ParamDef((L, d, H * (dn + dr)), dtype=dt),
+            "wkv_a": common.ParamDef((L, d, r + dr), dtype=dt),
+            "kv_norm": common.ParamDef((L, r), "zeros", dtype=dt),
+            "wkv_b": common.ParamDef((L, r, H * (dn + cfg.v_head_dim)), dtype=dt),
+            "wo": common.ParamDef((L, H * cfg.v_head_dim, d), dtype=dt),
+        })
+    else:
+        defs.update({
+            "wq": common.ParamDef((L, d, H * hd), dtype=dt),
+            "wk": common.ParamDef((L, d, KV * hd), dtype=dt),
+            "wv": common.ParamDef((L, d, KV * hd), dtype=dt),
+            "wo": common.ParamDef((L, H * hd, d), dtype=dt),
+        })
+    if moe_ffn:
+        lo, hi = cfg.experts_here
+        E = cfg.n_experts_eff
+        Eh = hi - lo if cfg.held_experts else E
+        defs.update({
+            "router": common.ParamDef((L, d, E), dtype=jnp.float32),
+            "we_gate": common.ParamDef((L, Eh, d, f), dtype=dt),
+            "we_up": common.ParamDef((L, Eh, d, f), dtype=dt),
+            "we_down": common.ParamDef((L, Eh, f, d), dtype=dt),
+        })
+        if cfg.router == "sigmoid":
+            defs["router_bias"] = common.ParamDef((L, E), scale=0.05,
+                                                  dtype=jnp.float32)
+        if cfg.n_shared_experts:
+            fs = f * cfg.n_shared_experts
+            defs.update({
+                "ws_gate": common.ParamDef((L, d, fs), dtype=dt),
+                "ws_up": common.ParamDef((L, d, fs), dtype=dt),
+                "ws_down": common.ParamDef((L, fs, d), dtype=dt),
+            })
+    else:
+        defs["w_gate"] = common.ParamDef((L, d, f), dtype=dt)
+        if not cfg.mlp_gelu():
+            defs["w_up"] = common.ParamDef((L, d, f), dtype=dt)
+        defs["w_down"] = common.ParamDef((L, f, d), dtype=dt)
+    return {f"{stack}/{k}": v for k, v in defs.items()}
+
+
 def param_defs(cfg: LMConfig) -> Dict[str, common.ParamDef]:
-    L, d, V = cfg.n_layers, cfg.d_model, cfg.vocab_size
-    H, KV, hd, f = cfg.n_heads, cfg.n_kv_heads, cfg.hd, cfg.d_ff
+    L, d, V, k = cfg.n_layers, cfg.d_model, cfg.vocab_size, cfg.first_k_dense
     dt = _dtype(cfg)
     defs = {
         "embed": common.ParamDef((V, d), "embed", dtype=dt),
         "final_norm": common.ParamDef((d,), "zeros", dtype=dt),
         "lm_head": common.ParamDef((d, V), dtype=dt),
-        "layers/ln1": common.ParamDef((L, d), "zeros", dtype=dt),
-        "layers/ln2": common.ParamDef((L, d), "zeros", dtype=dt),
-        "layers/wq": common.ParamDef((L, d, H * hd), dtype=dt),
-        "layers/wk": common.ParamDef((L, d, KV * hd), dtype=dt),
-        "layers/wv": common.ParamDef((L, d, KV * hd), dtype=dt),
-        "layers/wo": common.ParamDef((L, H * hd, d), dtype=dt),
     }
-    if cfg.moe:
-        E = cfg.n_experts_eff
-        defs.update({
-            "layers/router": common.ParamDef((L, d, E), dtype=jnp.float32),
-            "layers/we_gate": common.ParamDef((L, E, d, f), dtype=dt),
-            "layers/we_up": common.ParamDef((L, E, d, f), dtype=dt),
-            "layers/we_down": common.ParamDef((L, E, f, d), dtype=dt),
-        })
-        if cfg.n_shared_experts:
-            fs = f * cfg.n_shared_experts
-            defs.update({
-                "layers/ws_gate": common.ParamDef((L, d, fs), dtype=dt),
-                "layers/ws_up": common.ParamDef((L, d, fs), dtype=dt),
-                "layers/ws_down": common.ParamDef((L, fs, d), dtype=dt),
-            })
-    else:
-        defs["layers/w_gate"] = common.ParamDef((L, d, f), dtype=dt)
-        if not cfg.mlp_gelu():
-            defs["layers/w_up"] = common.ParamDef((L, d, f), dtype=dt)
-        defs["layers/w_down"] = common.ParamDef((L, f, d), dtype=dt)
+    defs.update(_stack_defs("layers", L - k, cfg, cfg.moe, cfg.d_ff))
+    if k:
+        defs.update(_stack_defs("dense_layers", k, cfg, False, cfg.dense_d_ff))
     return defs
 
 
@@ -98,6 +132,13 @@ def param_logical(cfg: LMConfig) -> Dict[str, Tuple]:
         "layers/wv": (None, "fsdp", "tp_kv"),
         "layers/wo": (None, "tp", "fsdp"),
     }
+    if cfg.mla:
+        del log["layers/wk"], log["layers/wv"]
+        log.update({"layers/wkv_a": (None, "fsdp", None),
+                    "layers/kv_norm": (None, None),
+                    "layers/wkv_b": (None, None, "tp")})
+    if cfg.router == "sigmoid":
+        log["layers/router_bias"] = (None, None)
     if cfg.moe:
         if cfg.moe_shard_mode() == "expert":
             log.update({
@@ -124,6 +165,14 @@ def param_logical(cfg: LMConfig) -> Dict[str, Tuple]:
         if not cfg.mlp_gelu():
             log["layers/w_up"] = (None, "fsdp", "tp")
         log["layers/w_down"] = (None, "tp", "fsdp")
+    if cfg.first_k_dense:
+        for k, v in list(log.items()):
+            if k.startswith("layers/") and not k.startswith(
+                    ("layers/router", "layers/we_", "layers/ws_")):
+                log["dense_" + k] = v
+        log.update({"dense_layers/w_gate": (None, "fsdp", "tp"),
+                    "dense_layers/w_up": (None, "fsdp", "tp"),
+                    "dense_layers/w_down": (None, "tp", "fsdp")})
     return log
 
 
@@ -149,6 +198,14 @@ def layer_is_global(cfg: LMConfig) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 # Blocks
 # ---------------------------------------------------------------------------
+def embed(params: PyTree, tokens: jnp.ndarray, cfg: LMConfig) -> jnp.ndarray:
+    """Token ids -> embeddings, times sqrt(d_model) where the config says."""
+    h = jnp.take(params["embed"], tokens, axis=0).astype(_dtype(cfg))
+    if cfg.embed_scale:
+        h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
+    return h
+
+
 def _qkv(x, lp, cfg: LMConfig, positions):
     B, S, _ = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -223,8 +280,7 @@ def hidden_states(params: PyTree, tokens: jnp.ndarray, cfg: LMConfig
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(B, S) tokens -> ((B, S, d) hidden, scalar aux loss)."""
     B, S = tokens.shape
-    h = jnp.take(params["embed"], tokens, axis=0).astype(_dtype(cfg))
-    h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
+    h = embed(params, tokens, cfg)
     h = shd.hint(h, "dp", None, None)
     positions = jnp.arange(S)
     windows = _layer_windows(cfg)
@@ -333,14 +389,23 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int) -> Dict[str, Any]:
             "length": jnp.zeros((), jnp.int32)}
 
 
-def prefill(params: PyTree, tokens: jnp.ndarray, cfg: LMConfig,
-            max_len: Optional[int] = None
+def prefill(params: PyTree, tokens: Optional[jnp.ndarray], cfg: LMConfig,
+            max_len: Optional[int] = None, *,
+            embeds: Optional[jnp.ndarray] = None,
+            lengths: Optional[jnp.ndarray] = None
             ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
-    """Forward pass that also returns the KV cache (padded to max_len)."""
-    B, S = tokens.shape
+    """Forward pass that also returns the KV cache (padded to max_len).
+
+    ``embeds`` (B, S, d) are input embeddings in place of ``tokens`` (a
+    VLM's image tokens and prompt).  A latent-attention config takes
+    right-padded rows: ``lengths`` (B,) real tokens each (default S; 0
+    for a padding row), and returns the logits at each row's last one."""
+    h = embed(params, tokens, cfg) if embeds is None else embeds
+    B, S = h.shape[:2]
     max_len = max_len or S
-    h = jnp.take(params["embed"], tokens, axis=0).astype(_dtype(cfg))
-    h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
+    if cfg.mla:
+        lengths = jnp.full((B,), S, jnp.int32) if lengths is None else lengths
+        return _mla_prefill(params, h, cfg, max_len, lengths)
     h = shd.hint(h, "dp", None, None)
     positions = jnp.arange(S)
     windows = _layer_windows(cfg)
@@ -366,12 +431,18 @@ def prefill(params: PyTree, tokens: jnp.ndarray, cfg: LMConfig,
 
 
 def decode_step(params: PyTree, cache: Dict[str, Any], tokens: jnp.ndarray,
-                cfg: LMConfig) -> Tuple[jnp.ndarray, Dict[str, Any]]:
-    """One decode step: tokens (B,) int32 -> (logits (B, V) f32, new cache)."""
+                cfg: LMConfig, valid: Optional[jnp.ndarray] = None
+                ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+    """One decode step: tokens (B,) int32 -> (logits (B, V) f32, new cache).
+
+    ``valid`` (B,): False for a padding row, whose token no expert
+    computes (latent-attention configs)."""
     B = tokens.shape[0]
+    if cfg.mla:
+        valid = jnp.ones((B,), bool) if valid is None else valid
+        return _mla_decode_step(params, cache, tokens, cfg, valid)
     pos = cache["length"]
-    h = jnp.take(params["embed"], tokens, axis=0)[:, None, :].astype(_dtype(cfg))
-    h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
+    h = embed(params, tokens, cfg)[:, None, :]
     positions = jnp.full((B, 1), pos, jnp.int32)
     windows = _layer_windows(cfg)
 
@@ -443,8 +514,7 @@ def decode_step_sliding(params: PyTree, cache: Dict[str, Any],
     g = cfg.global_every
     pos = cache["length"]
     ring = jnp.mod(pos, W)
-    h = jnp.take(params["embed"], tokens, axis=0)[:, None, :].astype(_dtype(cfg))
-    h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
+    h = embed(params, tokens, cfg)[:, None, :]
     positions = jnp.full((B, 1), pos, jnp.int32)
 
     # split stacked layer params into local / global stacks (static indices)
@@ -527,3 +597,166 @@ def decode_step_sliding(params: PyTree, cache: Dict[str, Any],
         "length": pos + 1,
     }
     return logits, new_cache
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (cfg.mla): dense stack, then MoE stack; latent cache
+# ---------------------------------------------------------------------------
+_MLA_ATTN = ("ln1", "wq", "wkv_a", "kv_norm", "wkv_b", "wo")
+
+
+def _at(stack: PyTree, i, names) -> PyTree:
+    """Layer ``i`` of the named leaves of a layer stack.  Called inside the
+    scope of the op that reads them, so that the slice is charged there."""
+    return {k: jax.lax.dynamic_index_in_dim(stack[k], i, 0, keepdims=False)
+            for k in names}
+
+
+def _mla_ffn(h: jnp.ndarray, stack: PyTree, i, cfg: LMConfig,
+             valid: jnp.ndarray, dense: bool):
+    """The FFN half of layer ``i`` of a stack on the float32 residual h (B,
+    S, d): (output, routed counts (2,) int32, the picked experts (B, S, K)
+    int16, None for a dense layer)."""
+    B, S, d = h.shape
+    dt = _dtype(cfg)
+    if dense:
+        with jax.named_scope("kernels.dense_mlp"):
+            lp = _at(stack, i, ("ln2", "w_gate", "w_up", "w_down"))
+            x = common.rms_norm(h, lp["ln2"], cfg.norm_eps).astype(dt)
+            g = jnp.einsum("bsd,df->bsf", x, lp["w_gate"])
+            u = jnp.einsum("bsd,df->bsf", x, lp["w_up"])
+            out = jnp.einsum("bsf,fd->bsd", common.swiglu(g, u), lp["w_down"])
+        return out, jnp.zeros((2,), jnp.int32), None
+    with jax.named_scope("kernels.moe_route"):
+        lp = _at(stack, i, ("ln2", "router", "router_bias"))
+        flat = common.rms_norm(h, lp["ln2"], cfg.norm_eps).reshape(B * S, d)
+    # the router reads the float32 norm, the experts its bfloat16 rounding;
+    # the experts' weights are read in place, layer i of the stacks
+    out, routed, experts = moe.moe_held(
+        flat, valid.reshape(-1), lp["router"], lp["router_bias"],
+        stack["we_gate"], stack["we_up"], stack["we_down"], top_k=cfg.top_k,
+        scale=cfg.routed_scale, first=cfg.experts_here[0], layer=i)
+    with jax.named_scope("kernels.moe_shared"):
+        lp = _at(stack, i, ("ws_gate", "ws_up", "ws_down"))
+        xb = flat.astype(dt)
+        g = jnp.einsum("td,df->tf", xb, lp["ws_gate"])
+        u = jnp.einsum("td,df->tf", xb, lp["ws_up"])
+        out = out + jnp.einsum("tf,fd->td", common.swiglu(g, u), lp["ws_down"])
+    return (out.reshape(B, S, d), routed,
+            experts.reshape(B, S, -1).astype(jnp.int16))
+
+
+def _mla_stacks(params: PyTree, cfg: LMConfig):
+    """(stack params, is dense, layers) in layer order."""
+    out = [(params["dense_layers"], True)] if cfg.first_k_dense else []
+    out.append((params["layers"], False))
+    return [(s, dense, jax.tree_util.tree_leaves(s)[0].shape[0])
+            for s, dense in out]
+
+
+def _scan_layers(body, carry, n: int):
+    """``lax.scan`` of ``body(carry, i)`` over layers ``0 .. n - 1``; a stack
+    of one layer runs once at index 0, which reads its weights in place."""
+    if n == 1:
+        carry, ys = body(carry, 0)
+        return carry, jax.tree.map(lambda y: y[None], ys)
+    return jax.lax.scan(body, carry, jnp.arange(n))
+
+
+def mla_forward(params: PyTree, h: jnp.ndarray, cfg: LMConfig,
+                valid: jnp.ndarray):
+    """Every layer over input embeddings h (B, S, d), causal, with the
+    residual stream in float32 and every matrix multiply in the weights'
+    dtype.  ``valid`` (B, S) is False on padding, which no expert computes.
+    Returns (final-normed hidden (B, S, d), latents (L, B, S, r + dr),
+    routed counts (2,) int32, the MoE layers' picked experts (L_moe, B,
+    S, K) int16)."""
+    from repro.models import mla
+    dt = _dtype(cfg)
+    positions = jnp.arange(h.shape[1])
+    lats, routed = [], jnp.zeros((2,), jnp.int32)
+    h = h.astype(jnp.float32)
+    for stack, dense, n in _mla_stacks(params, cfg):
+        def body(carry, i, stack=stack, dense=dense):
+            h, routed = carry
+            with jax.named_scope("kernels.mla_prefill"):
+                lp = _at(stack, i, _MLA_ATTN)
+                x = common.rms_norm(h, lp["ln1"], cfg.norm_eps).astype(dt)
+                o, lat = mla.prefill_attention(x, lp, cfg, positions)
+            h = h + o
+            f, k, picks = _mla_ffn(h, stack, i, cfg, valid, dense)
+            return (h + f, routed + k), (lat, picks)
+
+        (h, routed), (lat, picks) = _scan_layers(body, (h, routed), n)
+        lats.append(lat)
+    with jax.named_scope("kernels.lm_head"):
+        h = common.rms_norm(h, params["final_norm"], cfg.norm_eps).astype(dt)
+    return h, jnp.concatenate(lats, axis=0), routed, picks
+
+
+def _lm_head(params: PyTree, h: jnp.ndarray) -> jnp.ndarray:
+    with jax.named_scope("kernels.lm_head"):
+        return jnp.einsum("bd,dv->bv", h, params["lm_head"],
+                          preferred_element_type=jnp.float32)
+
+
+def _mla_prefill(params: PyTree, embeds: jnp.ndarray, cfg: LMConfig,
+                 max_len: int, lengths: jnp.ndarray):
+    B, S, _ = embeds.shape
+    valid = jnp.arange(S)[None, :] < lengths[:, None]
+    h, lats, routed, picks = mla_forward(params, embeds, cfg, valid)
+    last = h[jnp.arange(B), jnp.maximum(lengths - 1, 0)]
+    lats = jnp.pad(lats, ((0, 0), (0, 0), (0, max_len - S), (0, 0)))
+    return _lm_head(params, last), {"latent": lats, "length": lengths,
+                                    "routed": routed, "picks": picks}
+
+
+def mla_cache_specs(cfg: LMConfig, batch: int, max_len: int) -> Dict[str, Any]:
+    """The latent cache: ``[c; k_pe]`` per layer, row and position, each
+    row's valid length, and the routed counts of the pass so far."""
+    width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+    return {"latent": jax.ShapeDtypeStruct(
+                (cfg.n_layers, batch, max_len, width), _dtype(cfg)),
+            "length": jax.ShapeDtypeStruct((batch,), jnp.int32),
+            "routed": jax.ShapeDtypeStruct((2,), jnp.int32)}
+
+
+def _mla_decode_step(params: PyTree, cache: Dict[str, Any],
+                     tokens: jnp.ndarray, cfg: LMConfig,
+                     valid: jnp.ndarray):
+    """One token a row: each row's token goes to its own slot
+    ``cache["length"]``, where its RoPE position is too.  The new cache
+    holds the step's picked experts (L_moe, B, K) int16 under
+    ``"picks"``."""
+    from repro.models import mla
+    B = tokens.shape[0]
+    pos = cache["length"]
+    rows = jnp.arange(B)
+    h = embed(params, tokens, cfg)[:, None, :].astype(jnp.float32)
+    latent, routed = cache["latent"], cache["routed"]
+    first = 0
+    for stack, dense, n in _mla_stacks(params, cfg):
+        def body(carry, i, stack=stack, dense=dense, first=first):
+            h, latent, routed = carry
+            with jax.named_scope("kernels.mla_decode"):
+                lp = _at(stack, i, _MLA_ATTN)
+                x = common.rms_norm(h, lp["ln1"], cfg.norm_eps).astype(
+                    _dtype(cfg))
+                q_nope, q_pe, lat = mla.project(x, lp, cfg, pos[:, None])
+                latent = latent.at[first + i, rows, pos].set(
+                    lat[:, 0].astype(latent.dtype))
+                o = mla.decode_attention(q_nope, q_pe, latent[first + i],
+                                         pos + 1, lp, cfg)
+            h = h + o
+            f, k, picks = _mla_ffn(h, stack, i, cfg, valid[:, None], dense)
+            return (h + f, latent, routed + k), picks
+
+        (h, latent, routed), picks = _scan_layers(
+            body, (h, latent, routed), n)
+        first += n
+    with jax.named_scope("kernels.lm_head"):
+        h = common.rms_norm(h, params["final_norm"], cfg.norm_eps).astype(
+            _dtype(cfg))
+    return _lm_head(params, h[:, 0]), {"latent": latent, "length": pos + 1,
+                                       "routed": routed,
+                                       "picks": picks[:, :, 0]}

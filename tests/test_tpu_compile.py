@@ -14,6 +14,7 @@ cache is off around these compiles, since an entry written for a
 described chip cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -101,3 +102,45 @@ def test_deit_b_forward_compiles_for_v5e(one_chip):
     fwd = jax.jit(lambda p, x: jnp.argmax(vit.forward(p, x, cfg), -1))
     compiled = fwd.lower(params, imgs).compile()
     assert compiled.memory_analysis().argument_size_in_bytes > 150e6
+
+
+@pytest.fixture(scope="module")
+def kimi_decode(one_chip):
+    """The answer's decode loop at published widths, compiled for the chip."""
+    from repro.configs import get_config
+    from repro.models import kimi_vl, transformer
+    cfg = get_config("kimi-vl-a3b")
+    params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                          kimi_vl.param_specs(cfg))
+    cache = {k: _spec(one_chip, s.shape, s.dtype) for k, s in
+             transformer.mla_cache_specs(cfg.lm, 8, kimi_vl.max_len(
+                 cfg, 128)).items()}
+    return jax.jit(kimi_vl.decode, static_argnums=4).lower(
+        params, cache, _spec(one_chip, (8, cfg.lm.vocab_size)),
+        _spec(one_chip, (), jnp.int32), cfg).compile()
+
+
+def test_kimi_vl_decode_loop_compiles_for_v5e(kimi_decode):
+    """The answer's decode loop at published widths: the language model's
+    10.3 GB of bf16 weights (the tower's 0.9 GB are not its arguments) and
+    the latent cache of 8 rows, with the held experts' matrix multiplies
+    under their scope in the chip's program (XLA's own ragged dot drops
+    the name stack, and the trace would charge them to no scope)."""
+    text = kimi_decode.as_text()
+    assert re.search(r"convolution\(.*op_name=\"[^\"]*kernels\.moe_experts",
+                     text)
+    mem = kimi_decode.memory_analysis()
+    assert 10e9 < mem.argument_size_in_bytes < 11e9
+    # the loop's temporaries fit the chip's 16 GB beside all the weights
+    assert mem.temp_size_in_bytes < 2 * 2 ** 30
+
+
+def test_kimi_vl_decode_reads_expert_weights_in_place(kimi_decode):
+    """A decode step reads each touched expert's matrices from the stacks
+    where they lie: no layer's 16 held experts (3 x 92 MB) are copied out
+    of their stack, which a scan over the stacks does at every step."""
+    text = kimi_decode.as_text()
+    assert not re.search(r"= bf16\[(1,)?16,(2048,1408|1408,2048)\]", text)
+    # the expert loop slices one expert of one layer inside its multiply
+    assert re.search(r"dynamic-slice\(.*dynamic_slice_sizes=\{1,1,2048,1408\}",
+                     text)
