@@ -3,8 +3,9 @@
 On a TPU backend the kernels compile to Mosaic.  On any other backend they
 run in interpret mode — the kernel body evaluated as ordinary XLA, used
 for correctness validation — and say so with a ``RuntimeWarning`` when
-they are traced.  Model code selects kernels via the config's
-``attn_impl='pallas'``.
+they are traced.  Model code selects the attention kernel with the
+config's ``attn_impl='pallas'``; a ViT encoder's ``chunked`` attention on
+a TPU runs it too (``repro.models.vit.attention_impl``).
 """
 from __future__ import annotations
 
@@ -33,43 +34,39 @@ def _interpret() -> bool:
     return True
 
 
-def _pad_to(x: jnp.ndarray, mult: int, axis: int) -> jnp.ndarray:
-    size = x.shape[axis]
-    pad = (-size) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _attention(q, k, v, causal, window):
+    return _fa.flash_attention_fwd(q, k, v, causal=causal, window=window,
+                                   interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_k"))
+def _attention_fwd(q, k, v, causal, window):
+    return _attention(q, k, v, causal, window), (q, k, v)
+
+
+def _attention_bwd(causal, window, res, g):
+    # the gradient recomputes through the blocked XLA path, which holds
+    # one block of scores at a time too
+    from repro.models.attention import attention_chunked
+
+    def f(q, k, v):
+        out = attention_chunked(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v)),
+                                causal=causal, window=window)
+        return jnp.swapaxes(out, 1, 2)
+
+    return jax.vjp(f, *res)[1](g)
+
+
+_attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "window"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                    causal: bool = True, window: Optional[int] = None,
-                    block_q: int = 128, block_k: int = 128) -> jnp.ndarray:
-    """q (B,S,H,D); k,v (B,S,KV,D) -> (B,S,H,D). GQA via head mapping."""
-    B, S, H, D = q.shape
-    KV = k.shape[2]
-    G = H // KV
-    D_pad = (-D) % 128
-    # fold batch x heads; repeat is logical only (index_map equivalent):
-    # we expand KV to H by gathering, which XLA fuses into the kernel feed.
-    kh = jnp.repeat(k, G, axis=2)
-    vh = jnp.repeat(v, G, axis=2)
-    q3 = jnp.moveaxis(q, 2, 1).reshape(B * H, S, D)
-    k3 = jnp.moveaxis(kh, 2, 1).reshape(B * H, S, D)
-    v3 = jnp.moveaxis(vh, 2, 1).reshape(B * H, S, D)
-    if D_pad:
-        q3 = _pad_to(q3, 128, 2)
-        k3 = _pad_to(k3, 128, 2)
-        v3 = _pad_to(v3, 128, 2)
-    out = _fa.flash_attention_fwd(q3, k3, v3, causal=causal, window=window,
-                                  block_q=block_q, block_k=block_k,
-                                  sm_scale=D ** -0.5,
-                                  interpret=_interpret())
-    out = out[:, :, :D].reshape(B, H, S, D)
-    return jnp.moveaxis(out, 1, 2)
+                    causal: bool = True, window: Optional[int] = None
+                    ) -> jnp.ndarray:
+    """q (B,H,S,D); k,v (B,KV,S,D) head-major -> (B,H,S,D). GQA via the
+    kernel's head mapping; block sizes follow from S."""
+    return _attention(q, k, v, causal, window)
 
 
 @jax.jit

@@ -175,17 +175,37 @@ def attention_decode(q, k_cache, v_cache, cache_len, *, window=None
     return out.reshape(B, 1, H, D).astype(q.dtype)
 
 
+def attention_path(impl: str, seq: int, q_chunk: int) -> str:
+    """The implementation :func:`attention` runs: ``"naive"``, ``"chunked"``
+    (the XLA loop) or ``"kernel"`` (the Pallas kernel).  A sequence of at
+    most one chunk is naive whatever ``impl`` says."""
+    if impl not in ("naive", "chunked", "pallas"):
+        raise ValueError(f"unknown attention impl {impl!r}")
+    if impl == "naive" or seq <= q_chunk:
+        return "naive"
+    return "kernel" if impl == "pallas" else "chunked"
+
+
 def attention(q, k, v, *, causal=True, window=None, impl="chunked",
-              q_chunk: int = 1024, q_offset: int = 0) -> jnp.ndarray:
-    if impl == "naive" or q.shape[1] <= q_chunk:
-        # single-chunk sequences: the naive path IS the blocked path
-        return attention_naive(q, k, v, causal=causal, window=window,
-                               q_offset=q_offset)
-    if impl == "chunked":
-        return attention_chunked(q, k, v, causal=causal, window=window,
-                                 q_chunk=q_chunk, kv_chunk=q_chunk,
-                                 q_offset=q_offset)
-    if impl == "pallas":
+              q_chunk: int = 1024, q_offset: int = 0,
+              heads_first: bool = False) -> jnp.ndarray:
+    """``heads_first``: q, k, v and the output are head-major (B, H, S, D),
+    the layout the kernel reads; the XLA paths swap them to token-major
+    and back."""
+    seq = q.shape[2] if heads_first else q.shape[1]
+    path = attention_path(impl, seq, q_chunk)
+    swap = heads_first != (path == "kernel")
+    if swap:
+        q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+    if path == "kernel":
         from repro.kernels import ops as kops
-        return kops.flash_attention(q, k, v, causal=causal, window=window)
-    raise ValueError(f"unknown attention impl {impl!r}")
+        out = kops.flash_attention(q, k, v, causal=causal, window=window)
+    elif path == "naive":
+        # single-chunk sequences: the naive path IS the blocked path
+        out = attention_naive(q, k, v, causal=causal, window=window,
+                              q_offset=q_offset)
+    else:
+        out = attention_chunked(q, k, v, causal=causal, window=window,
+                                q_chunk=q_chunk, kv_chunk=q_chunk,
+                                q_offset=q_offset)
+    return jnp.swapaxes(out, 1, 2) if swap else out

@@ -123,13 +123,26 @@ def rope_2d_tables(head_dim: int, rows: int, cols: int,
     return jnp.cos(ang), jnp.sin(ang)
 
 
-def _apply_rope_2d(x: jnp.ndarray, rope) -> jnp.ndarray:
-    """x (B, N, H, D), rotated pairwise by the (N, D/2) tables."""
-    cos, sin = (t[None, :, None, :] for t in rope)
+def _apply_rope_2d(x: jnp.ndarray, rope, seq_axis: int = 1) -> jnp.ndarray:
+    """x (B, N, H, D), or (B, H, N, D) with ``seq_axis`` 2, rotated pairwise
+    by the (N, D/2) tables."""
+    cos, sin = (jnp.expand_dims(t, (0, 3 - seq_axis)) for t in rope)
     xf = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
     a, b = xf[..., 0], xf[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], -1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def attention_impl(cfg: ViTConfig, platform: str) -> str:
+    """The ``impl`` the encoder's attention runs.  On a TPU a blocked
+    (``chunked``) encoder runs the Pallas kernel, which keeps the score
+    tiles on chip; only MoonViT's 2,304 patches exceed a chunk.  Training
+    through it pays one more forward of the attention, which the kernel's
+    gradient recomputes through the XLA loop.  Elsewhere the kernel would
+    run in Pallas's interpreter, so the config's own ``impl`` stands."""
+    if cfg.attn_impl == "chunked" and platform == "tpu":
+        return "pallas"
+    return cfg.attn_impl
 
 
 def encoder_block(h: jnp.ndarray, lp: PyTree, cfg: ViTConfig,
@@ -139,16 +152,30 @@ def encoder_block(h: jnp.ndarray, lp: PyTree, cfg: ViTConfig,
     B, S, d = h.shape
     nh = cfg.n_heads
     hd = d // nh
+    impl = attention_impl(cfg, jax.default_backend())
+    # the kernel reads head-major (B, H, S, D) tiles; the XLA paths token-major
+    heads_first = attn.attention_path(impl, S, cfg.attn_chunk) == "kernel"
     with jax.named_scope("kernels.vit_block"):
         y = common.layer_norm(h, lp["ln1"]["scale"], lp["ln1"]["bias"])
-        q = (jnp.einsum("bsd,dh->bsh", y, lp["wq"]) + lp["bq"]).reshape(B, S, nh, hd)
-        k = (jnp.einsum("bsd,dh->bsh", y, lp["wk"]) + lp["bk"]).reshape(B, S, nh, hd)
-        v = (jnp.einsum("bsd,dh->bsh", y, lp["wv"]) + lp["bv"]).reshape(B, S, nh, hd)
+
+        def heads(w, b):
+            if heads_first:
+                return (jnp.einsum("bsd,dhk->bhsk", y, w.reshape(d, nh, hd))
+                        + b.reshape(nh, 1, hd))
+            return (jnp.einsum("bsd,dh->bsh", y, w) + b).reshape(B, S, nh, hd)
+
+        q, k, v = (heads(lp[w], lp[b]) for w, b in
+                   (("wq", "bq"), ("wk", "bk"), ("wv", "bv")))
         if rope is not None:
-            q, k = _apply_rope_2d(q, rope), _apply_rope_2d(k, rope)
-        o = attn.attention(q, k, v, causal=False, impl=cfg.attn_impl,
-                           q_chunk=cfg.attn_chunk)
-        h = h + jnp.einsum("bsh,hd->bsd", o.reshape(B, S, d), lp["wo"]) + lp["bo"]
+            q, k = (_apply_rope_2d(x, rope, 2 if heads_first else 1)
+                    for x in (q, k))
+        o = attn.attention(q, k, v, causal=False, impl=impl,
+                           q_chunk=cfg.attn_chunk, heads_first=heads_first)
+        if heads_first:
+            o = jnp.einsum("bhsk,hkd->bsd", o, lp["wo"].reshape(nh, hd, d))
+        else:
+            o = jnp.einsum("bsh,hd->bsd", o.reshape(B, S, d), lp["wo"])
+        h = h + o + lp["bo"]
         y2 = common.layer_norm(h, lp["ln2"]["scale"], lp["ln2"]["bias"])
         z = common.gelu(jnp.einsum("bsd,df->bsf", y2, lp["w_in"]) + lp["b_in"])
         h = h + jnp.einsum("bsf,fd->bsd", z, lp["w_out"]) + lp["b_out"]

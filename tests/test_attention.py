@@ -2,6 +2,8 @@
 numerically equivalent to the naive oracle across GQA ratios, windows,
 offsets and ragged lengths — this is the path every full-scale dry-run
 lowers."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,8 +11,9 @@ import pytest
 pytest.importorskip("hypothesis")  # property tests skip cleanly without it
 from hypothesis import given, settings, strategies as st
 
-from repro.models.attention import (apply_rope, attention_chunked,
-                                    attention_decode, attention_naive)
+from repro.models.attention import (apply_rope, attention,
+                                    attention_chunked, attention_decode,
+                                    attention_naive, attention_path)
 
 
 def _qkv(B, S, H, KV, D, seed=0):
@@ -98,3 +101,67 @@ def test_gqa_reduces_to_mha_when_kv_repeated():
     mha = attention_naive(q, k_rep, v_rep, causal=True)
     np.testing.assert_allclose(np.asarray(gqa), np.asarray(mha),
                                rtol=1e-5, atol=1e-5)
+
+
+def _tower_tokens(cfg):
+    """Patch tokens of one frame through a served VLM's tower."""
+    rows, cols = (n // cfg.vision.patch for n in cfg.frame_hw)
+    return rows * cols
+
+
+def _cell_tower(impl="chunked"):
+    """MoonViT as the benchmark's Kimi-VL cell builds it: 768-token chunks."""
+    from repro.configs import get_config
+    return dataclasses.replace(get_config("kimi-vl-a3b").vision,
+                               attn_impl=impl, attn_chunk=768)
+
+
+def test_attention_path_by_length():
+    """The length decides: MoonViT's 2,304 patches take the kernel on a TPU,
+    DeiT-B's 198 tokens and the smoke tower's 24 the naive path on any
+    platform; the UNet's 4,096-token self-attention keeps the XLA loop."""
+    from repro.configs import get_config, get_smoke_config
+    from repro.models import vit
+    moon, smoke, deit = (get_config("kimi-vl-a3b"),
+                         get_smoke_config("kimi-vl-a3b"), get_config("deit-b"))
+    assert _tower_tokens(moon) == 2304 and _tower_tokens(smoke) == 24
+    assert deit.n_tokens() == 198
+    for platform in ("tpu", "cpu"):
+        for cfg, n, path in ((_cell_tower(), 2304,
+                              "kernel" if platform == "tpu" else "chunked"),
+                             (smoke.vision, 24, "naive"),
+                             (deit, 198, "naive")):
+            impl = vit.attention_impl(cfg, platform)
+            assert attention_path(impl, n, cfg.attn_chunk) == path
+    assert attention_path("chunked", 4096, 1024) == "chunked"
+
+
+@pytest.mark.parametrize("impl,platform,path", [
+    ("chunked", "tpu", "kernel"),     # the tower as the benchmark's cell runs
+    ("chunked", "cpu", "chunked"),    # no kernel outside its interpreter
+    ("pallas", "cpu", "kernel"),
+    ("naive", "tpu", "naive"),
+])
+def test_attention_path_of_blocked_attention(impl, platform, path):
+    """A ViT encoder's path past one chunk; within one chunk it is naive."""
+    from repro.models import vit
+    chosen = vit.attention_impl(_cell_tower(impl), platform)
+    assert attention_path(chosen, 2304, 768) == path
+    assert attention_path(chosen, 768, 768) == "naive"
+
+
+def test_attention_path_refuses_unknown_impl():
+    with pytest.raises(ValueError):
+        attention_path("flash", 2304, 512)
+
+
+@pytest.mark.parametrize("impl", ["naive", "chunked", "pallas"])
+def test_heads_first_matches_token_major(impl):
+    """Head-major q, k, v give the head-major output of the token-major
+    call, whichever path the length picks."""
+    q, k, v = _qkv(2, 100, 4, 2, 72, seed=5)
+    want = attention(q, k, v, causal=False, impl=impl, q_chunk=32)
+    got = attention(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v)),
+                    causal=False, impl=impl, q_chunk=32, heads_first=True)
+    np.testing.assert_allclose(np.asarray(jnp.swapaxes(got, 1, 2)),
+                               np.asarray(want), rtol=2e-5, atol=2e-5)

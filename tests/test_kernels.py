@@ -21,49 +21,103 @@ def _allclose(a, b, dtype):
 
 
 # ---------------------------------------------------------------------------
-# flash attention
+# flash attention: the kernel reads head-major (B, H, S, D); the oracles
+# take token-major (B, S, H, D)
 # ---------------------------------------------------------------------------
+def _heads_first(*xs):
+    return [jnp.swapaxes(x, 1, 2) for x in xs]
+
+
+def _qkv(B, S, H, KV, D, seed, dtype=jnp.float32):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return [jax.random.normal(kk, (B, S, h, D), jnp.float32).astype(dtype)
+            for kk, h in zip(keys, (H, KV, KV))]
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("B,S,H,KV,D", [
     (1, 128, 4, 4, 64),        # MHA, single block
-    (2, 256, 4, 2, 64),        # GQA 2:1, two kv blocks
-    (1, 384, 8, 2, 128),       # GQA 4:1, non-128 seq multiple handled by pad
-    (2, 100, 4, 1, 80),        # MQA, ragged seq + non-128 head dim (padded)
+    (2, 256, 4, 2, 64),        # GQA 2:1
+    (1, 384, 8, 2, 128),       # GQA 4:1
+    (2, 100, 4, 1, 80),        # MQA, a length no tile divides
+    (2, 48, 4, 4, 72),         # MoonViT's head width, the tile's full last dim
 ])
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_attention_matches_ref(B, S, H, KV, D, dtype, causal):
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = (jax.random.normal(k1, (B, S, H, D), jnp.float32)).astype(dtype)
-    k = (jax.random.normal(k2, (B, S, KV, D), jnp.float32)).astype(dtype)
-    v = (jax.random.normal(k3, (B, S, KV, D), jnp.float32)).astype(dtype)
-    got = ops.flash_attention(q, k, v, causal=causal)
+    q, k, v = _qkv(B, S, H, KV, D, 0, dtype)
+    got = ops.flash_attention(*_heads_first(q, k, v), causal=causal)
     want = ref.flash_attention_ref(q, k, v, causal=causal)
-    _allclose(got, want, dtype)
+    _allclose(jnp.swapaxes(got, 1, 2), want, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("S,block_q,block_k,causal,window", [
+    (256, 64, 128, False, None),   # blocks divide S, as 1,152 divides 2,304
+    (200, 64, 128, False, None),   # ragged: edge blocks in q and in keys
+    (200, 64, 128, True, 48),      # causal and windowed across blocks
+])
+def test_flash_attention_blocks_match_naive(S, block_q, block_k, causal,
+                                            window, dtype):
+    """Several q and KV blocks at head width 72 (MoonViT's), against the
+    model's naive attention at the same precision."""
+    from repro.kernels.flash_attention import flash_attention_fwd
+    from repro.models.attention import attention_naive
+    q, k, v = _qkv(2, S, 4, 2, 72, S, dtype)
+    got = flash_attention_fwd(*_heads_first(q, k, v), causal=causal,
+                              window=window, block_q=block_q,
+                              block_k=block_k, interpret=True)
+    want = attention_naive(q, k, v, causal=causal, window=window)
+    _allclose(jnp.swapaxes(got, 1, 2), want, dtype)
+
+
+@pytest.mark.parametrize("S,blocks", [
+    (2304, (576, 2304)),    # MoonViT's 36 x 64 patches: all keys at once
+    (4096, (512, 2048)),
+    (198, (198, 198)),      # one block: the whole sequence
+    (2500, (576, 2304)),    # nothing aligned divides it: ragged edges
+])
+def test_block_sizes_follow_the_length(S, blocks):
+    from repro.kernels.flash_attention import block_sizes
+    assert block_sizes(S, S) == blocks
 
 
 @pytest.mark.parametrize("window", [16, 64, 1024])
 def test_flash_attention_sliding_window(window):
-    B, S, H, KV, D = 1, 256, 4, 2, 64
-    keys = jax.random.split(jax.random.PRNGKey(1), 3)
-    q = jax.random.normal(keys[0], (B, S, H, D))
-    k = jax.random.normal(keys[1], (B, S, KV, D))
-    v = jax.random.normal(keys[2], (B, S, KV, D))
-    got = ops.flash_attention(q, k, v, causal=True, window=window)
+    q, k, v = _qkv(1, 256, 4, 2, 64, 1)
+    got = ops.flash_attention(*_heads_first(q, k, v), causal=True,
+                              window=window)
     want = ref.flash_attention_ref(q, k, v, causal=True, window=window)
-    _allclose(got, want, jnp.float32)
+    _allclose(jnp.swapaxes(got, 1, 2), want, jnp.float32)
 
 
 def test_flash_attention_matches_model_attention():
     """Kernel agrees with the model's chunked XLA attention path."""
     from repro.models.attention import attention
-    B, S, H, KV, D = 2, 256, 4, 2, 64
-    keys = jax.random.split(jax.random.PRNGKey(2), 3)
-    q = jax.random.normal(keys[0], (B, S, H, D))
-    k = jax.random.normal(keys[1], (B, S, KV, D))
-    v = jax.random.normal(keys[2], (B, S, KV, D))
-    got = ops.flash_attention(q, k, v, causal=True)
+    q, k, v = _qkv(2, 256, 4, 2, 64, 2)
+    got = ops.flash_attention(*_heads_first(q, k, v), causal=True)
     want = attention(q, k, v, causal=True, impl="chunked", q_chunk=64)
-    _allclose(got, want, jnp.float32)
+    _allclose(jnp.swapaxes(got, 1, 2), want, jnp.float32)
+
+
+def test_flash_attention_gradient_matches_naive():
+    """The kernel's gradient (recomputed through the blocked XLA path) is
+    the naive attention's."""
+    from repro.models.attention import attention_naive
+    q, k, v = _qkv(1, 96, 4, 2, 72, 3)
+    w = jax.random.normal(jax.random.PRNGKey(4), q.shape)
+
+    def loss_kernel(q, k, v):
+        out = ops.flash_attention(*_heads_first(q, k, v), causal=False)
+        return jnp.sum(jnp.swapaxes(out, 1, 2) * w)
+
+    def loss_naive(q, k, v):
+        return jnp.sum(attention_naive(q, k, v, causal=False) * w)
+
+    got = jax.grad(loss_kernel, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss_naive, argnums=(0, 1, 2))(q, k, v)
+    for g, w_ in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w_),
+                                   rtol=1e-4, atol=1e-4)
 
 
 @settings(max_examples=10, deadline=None)
@@ -71,14 +125,10 @@ def test_flash_attention_matches_model_attention():
        st.sampled_from([(4, 4), (4, 2), (8, 1)]), st.booleans())
 def test_flash_attention_property(B, S, HKV, causal):
     H, KV = HKV
-    D = 64
-    keys = jax.random.split(jax.random.PRNGKey(S * 7 + B), 3)
-    q = jax.random.normal(keys[0], (B, S, H, D))
-    k = jax.random.normal(keys[1], (B, S, KV, D))
-    v = jax.random.normal(keys[2], (B, S, KV, D))
-    got = ops.flash_attention(q, k, v, causal=causal)
+    q, k, v = _qkv(B, S, H, KV, 64, S * 7 + B)
+    got = ops.flash_attention(*_heads_first(q, k, v), causal=causal)
     want = ref.flash_attention_ref(q, k, v, causal=causal)
-    _allclose(got, want, jnp.float32)
+    _allclose(jnp.swapaxes(got, 1, 2), want, jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -2,8 +2,9 @@
 
 Each test compiles for one chip of a *described* TPU v5e (2x2) host:
 the Mosaic ``event_select`` kernel at fleet widths, the 256-node
-fleetsim scan (jnp path and kernel path) and the deit-b forward at its
-published widths.  Nothing runs; a compile that passes here is not a
+fleetsim scan (jnp path and kernel path), the deit-b forward at its
+published widths, a MoonViT block through the attention kernel and the
+Kimi-VL decode loop.  Nothing runs; a compile that passes here is not a
 chip run.  It catches what interpret mode cannot: block shapes the TPU
 refuses, scalars in vector memory, programs that do not fit.
 
@@ -13,6 +14,7 @@ parallel test run all import this file.  The persistent compilation
 cache is off around these compiles, since an entry written for a
 described chip cannot be read back without one.
 """
+import dataclasses
 import os
 import re
 
@@ -102,6 +104,38 @@ def test_deit_b_forward_compiles_for_v5e(one_chip):
     fwd = jax.jit(lambda p, x: jnp.argmax(vit.forward(p, x, cfg), -1))
     compiled = fwd.lower(params, imgs).compile()
     assert compiled.memory_analysis().argument_size_in_bytes > 150e6
+
+
+def test_moonvit_block_attention_is_the_kernel_for_v5e(one_chip,
+                                                       monkeypatch):
+    """One MoonViT encoder block at the served shape (8 frames of 36 x 64
+    patches, d 1,152): attention is the Mosaic kernel, charged to the
+    block's scope and no nested one (``vision_mfu`` divides by that
+    scope's time), and no f32 (2,304 x 2,304) score matrix per head sits
+    in HBM (2.72 GB of temporaries on the naive path)."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import vit
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    # the tower as the benchmark's cell builds it, with the impl a TPU picks
+    cfg = dataclasses.replace(get_config("kimi-vl-a3b").vision,
+                              attn_impl="chunked", attn_chunk=768)
+    cfg = dataclasses.replace(cfg, attn_impl=vit.attention_impl(cfg, "tpu"))
+    hd = cfg.d_model // cfg.n_heads
+    layer = jax.tree.map(lambda a: _spec(one_chip, a.shape[1:], a.dtype),
+                         vit.param_specs(cfg)["layers"])
+    rope = tuple(_spec(one_chip, t.shape) for t in
+                 jax.eval_shape(lambda: vit.rope_2d_tables(hd, 36, 64)))
+    h = _spec(one_chip, (8, 36 * 64, cfg.d_model), jnp.bfloat16)
+    compiled = jax.jit(lambda h, lp, r: vit.encoder_block(h, lp, cfg, r)
+                       ).lower(h, layer, rope).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if f'custom_call_target="{KERNEL}"' in line]
+    assert calls
+    for line in calls:
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert re.findall(r"kernels\.\w+", op_name) == ["kernels.vit_block"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
 @pytest.fixture(scope="module")
