@@ -66,8 +66,7 @@ def bench_python(wl: UniformWorkload, topology: Topology, policy: str,
 
 
 def bench_fleetsim(wl: UniformWorkload, topology: Topology, policy: str,
-                   capacity: int, depth: int,
-                   use_pallas: bool = False) -> Tuple[float, dict]:
+                   capacity: int, depth: int) -> Tuple[float, dict]:
     """Steady-state requests/sec (warm call: same trace cache, new seed).
 
     ``event_overflow`` is asserted 0, so an undersized event buffer cannot
@@ -75,8 +74,7 @@ def bench_fleetsim(wl: UniformWorkload, topology: Topology, policy: str,
     ta = topology_arrays(topology)
     reqs, _ = wl.to_arrays(0)
     R = reqs.arrival.shape[0]
-    kw = dict(policy=policy, capacity=capacity, depth=depth,
-              use_pallas=use_pallas)
+    kw = dict(policy=policy, capacity=capacity, depth=depth)
     # cold call on seed 0 (compile + run), warm measurement on seed 1 —
     # same compiled executable, fresh forwarding stream
     cw = cold_warm(lambda: simulate(reqs, ta, SimParams.make(0), **kw),
@@ -143,11 +141,7 @@ def run(smoke: bool = False, full: bool = False,
             py_rps = None
             if not skip_py:
                 py_rps, _ = bench_python(wl, topo, policy)
-            # exercise the Pallas kernel (interpret off-TPU) in the smoke
-            # cell so CI covers it; the measured cells use the jnp reference
-            use_pallas = smoke and policy == "batched_feasible"
-            fs_rps, fs_info = bench_fleetsim(wl, topo, policy, cap, dep,
-                                             use_pallas=use_pallas)
+            fs_rps, fs_info = bench_fleetsim(wl, topo, policy, cap, dep)
             ratio = (fs_rps / py_rps) if py_rps else float("nan")
             tag = f"{fs_rps:,.0f} req/s fleetsim"
             if py_rps:
@@ -193,8 +187,7 @@ def run(smoke: bool = False, full: bool = False,
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
-                    help="tiny fleets, CI-friendly runtime, Pallas "
-                         "interpret path exercised")
+                    help="tiny fleets, CI-friendly runtime")
     ap.add_argument("--full", action="store_true",
                     help="include python batched_feasible @ 256 nodes "
                          "(very slow)")

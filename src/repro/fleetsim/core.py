@@ -31,11 +31,11 @@ deadlines and request ids) next to per-node ``head``/``busy_until``/
 2. **test + route** — the event's node is scored by one feasibility +
    geometry pass over its live window; an infeasible, non-exhausted
    request picks its forwarding target *now*, at true event time (loads,
-   rng, the trace row, and — for ``batched_feasible`` — the fused
-   per-hop ``link_cost`` mask of the :func:`repro.kernels.ops.
-   event_select` kernel all reflect every earlier event), and emits a
-   re-arrival event at ``t + transfer_delay`` instead of resolving the
-   chain speculatively at the source step;
+   rng, the trace row, and — for ``batched_feasible`` — the per-hop
+   feasibility mask of :func:`repro.fleetsim.scoring.event_select` all
+   reflect every earlier event), and emits a re-arrival event at
+   ``t + transfer_delay`` instead of resolving the chain speculatively
+   at the source step;
 3. **apply** — feasible insert at the pre-computed (slot, window) pair,
    forced tail-append, or discard as ``where``-selects; an idle CPU
    short-circuits the insert (the host engine pushes then immediately
@@ -70,7 +70,7 @@ import jax.numpy as jnp
 from repro.core import jax_queue as jq
 from repro.fleetsim.arrays import (RequestArrays, TopologyArrays,
                                    event_bound)
-from repro.kernels import ref as kref
+from repro.fleetsim.scoring import event_select, fleet_search
 from repro.netsim.link import NetParams
 from repro.telemetry.timeline import (TelemetryConfig, TelemetryFrame,
                                       bucket_of, bucket_width,
@@ -291,7 +291,7 @@ def _route_next(policy: str, topo: TopologyArrays, load, cur, key, hop,
 # ---------------------------------------------------------------------------
 def _estep(state: EventState, _, *, topo: TopologyArrays, key, policy: str,
            max_forwards: int, discard_on_exhaust: bool, capacity: int,
-           depth: int, use_pallas: bool, R: int, use_network: bool,
+           depth: int, R: int, use_network: bool,
            net: Optional[NetParams], fresh_cols, rear_cols, targets,
            zero_net, hop_bits: int, tel_buckets: Optional[int] = None,
            tel_width=None) -> Tuple[EventState, None]:
@@ -352,14 +352,13 @@ def _estep(state: EventState, _, *, topo: TopologyArrays, key, policy: str,
 
     feas_all = j_all = cap_all = None
     if policy == "batched_feasible":
-        # the event_select kernel's slot in the step: the two-way merge and
-        # the per-hop link_cost candidate mask fused into one pass over the
-        # whole fleet's live windows.  The kernel re-derives the merge from
-        # the same candidate scalars (bit-identical to the jnp merge above,
-        # and load-bearing inside: the selected node picks which latency /
-        # inverse-bandwidth row the scoring reads).  Both scorers run under
-        # "kernels.event_select" (the Pallas wrapper sets it itself), the
-        # window building before them under "fleetsim.windows".
+        # every node scored at the event's wire-delayed arrival, in one
+        # pass over the whole fleet's live windows.  event_select re-derives
+        # the merge from the same candidate scalars (bit-identical to the
+        # merge above; the selected node picks which latency /
+        # inverse-bandwidth row the scoring reads).  The scope names are
+        # what `select_us` reads: the windows under "fleetsim.windows", the
+        # scoring under "kernels.event_select".
         with jax.named_scope("fleetsim.windows"):
             w0_all = jnp.clip(state.head, 0, capacity - W)
             win_all = lambda a: _row_windows(a, w0_all, W)
@@ -368,16 +367,9 @@ def _estep(state: EventState, _, *, topo: TopologyArrays, key, policy: str,
                     win_all(state.sizes))
         lat, ibw = (net.latency, net.inv_bw) if use_network \
             else (zero_net, zero_net)
-        if use_pallas:
-            from repro.kernels import ops as kops
-            sel = kops.event_select(
-                *cand_a, *cand_b, *wins, state.nq, hrel_all, topo.speeds,
-                state.busy, lat, ibw)
-        else:
-            with jax.named_scope("kernels.event_select"):
-                sel = kref.event_select_ref(
-                    *cand_a, *cand_b, *wins, state.nq, hrel_all,
-                    topo.speeds, state.busy, lat, ibw)
+        with jax.named_scope("kernels.event_select"):
+            sel = event_select(*cand_a, *cand_b, *wins, state.nq, hrel_all,
+                               topo.speeds, state.busy, lat, ibw)
         take_fresh, t, cur, feas_all, _, j_all, cap_all, _ = sel
 
     # -- admission test at the event's node -------------------------------
@@ -398,7 +390,7 @@ def _estep(state: EventState, _, *, topo: TopologyArrays, key, policy: str,
             ok = feas_all[cur]
             j, cap = j_all[cur], cap_all[cur]
         else:
-            okv, jv, capv, _ = kref.fleet_search_ref(
+            okv, jv, capv, _ = fleet_search(
                 starts_w[None], ends_w[None], sizes_w[None],
                 state.nq[cur][None], ps[cur][None], d, cpu_free_c[None],
                 hrel_c[None])
@@ -521,14 +513,13 @@ def _estep(state: EventState, _, *, topo: TopologyArrays, key, policy: str,
 # ---------------------------------------------------------------------------
 @functools.partial(
     jax.jit, static_argnames=("policy", "max_forwards", "discard_on_exhaust",
-                              "capacity", "depth", "use_pallas",
-                              "use_network", "max_events", "event_buf",
-                              "tel_buckets", "tel_horizon"))
+                              "capacity", "depth", "use_network",
+                              "max_events", "event_buf", "tel_buckets",
+                              "tel_horizon"))
 def _simulate(reqs: RequestArrays, topo: TopologyArrays, params: SimParams,
               targets: jnp.ndarray, net: Optional[NetParams] = None, *,
               policy: str, max_forwards: int, discard_on_exhaust: bool,
-              capacity: int, depth: int, use_pallas: bool,
-              use_network: bool = False,
+              capacity: int, depth: int, use_network: bool = False,
               max_events: Optional[int] = None,
               event_buf: Optional[int] = None,
               tel_buckets: Optional[int] = None,
@@ -591,7 +582,7 @@ def _simulate(reqs: RequestArrays, topo: TopologyArrays, params: SimParams,
         step = functools.partial(
             _estep, topo=topo, key=key, policy=policy,
             max_forwards=max_forwards, discard_on_exhaust=discard_on_exhaust,
-            capacity=capacity, depth=depth, use_pallas=use_pallas, R=R,
+            capacity=capacity, depth=depth, R=R,
             use_network=use_network, net=net, fresh_cols=fresh_cols,
             rear_cols=rear_cols, targets=targets,
             zero_net=jnp.zeros((K, K), dt), hop_bits=hop_bits,
@@ -693,7 +684,6 @@ def simulate(reqs: RequestArrays, topo: TopologyArrays,
              max_forwards: int = 2, discard_on_exhaust: bool = False,
              capacity: int = 256, depth: Optional[int] = None,
              targets: Optional[jnp.ndarray] = None,
-             use_pallas: bool = False,
              net: Optional[NetParams] = None,
              max_events: Optional[int] = None,
              event_buf: Optional[int] = None,
@@ -777,7 +767,7 @@ def simulate(reqs: RequestArrays, topo: TopologyArrays,
     return _simulate(reqs, topo, params, jnp.asarray(targets, jnp.int32),
                      net, policy=policy, max_forwards=max_forwards,
                      discard_on_exhaust=discard_on_exhaust,
-                     capacity=capacity, depth=depth, use_pallas=use_pallas,
+                     capacity=capacity, depth=depth,
                      use_network=use_network, max_events=max_events,
                      event_buf=event_buf, tel_buckets=tel_buckets,
                      tel_horizon=tel_horizon)
@@ -785,8 +775,8 @@ def simulate(reqs: RequestArrays, topo: TopologyArrays,
 
 def simulate_fn(*, policy: str = "random", max_forwards: int = 2,
                 discard_on_exhaust: bool = False, capacity: int = 256,
-                depth: Optional[int] = None, use_pallas: bool = False,
-                network: bool = False, max_events: Optional[int] = None,
+                depth: Optional[int] = None, network: bool = False,
+                max_events: Optional[int] = None,
                 event_buf: Optional[int] = None,
                 telemetry: Optional[TelemetryConfig] = None):
     """The jitted simulator with statics bound — the thing to ``jax.vmap``.
@@ -831,6 +821,6 @@ def simulate_fn(*, policy: str = "random", max_forwards: int = 2,
         _simulate, policy=policy, max_forwards=max_forwards,
         discard_on_exhaust=discard_on_exhaust, capacity=capacity,
         depth=capacity if depth is None else min(depth, capacity),
-        use_pallas=use_pallas, use_network=network, max_events=max_events,
+        use_network=network, max_events=max_events,
         event_buf=event_buf, tel_buckets=tel_buckets,
         tel_horizon=tel_horizon)

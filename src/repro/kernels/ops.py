@@ -1,4 +1,5 @@
-"""Jitted public wrappers for the Pallas kernels.
+"""Jitted public wrappers for the model layers' Pallas kernels:
+attention, RMSNorm and the grouped expert GEMM.
 
 On a TPU backend the kernels compile to Mosaic.  On any other backend they
 run in interpret mode — the kernel body evaluated as ordinary XLA, used
@@ -16,10 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import event_select as _es
 from repro.kernels import flash_attention as _fa
-from repro.kernels import fleet_feasibility as _ff
-from repro.kernels import link_cost as _lc
 from repro.kernels import moe_gemm as _mg
 from repro.kernels import rmsnorm as _rn
 
@@ -82,63 +80,3 @@ def rmsnorm(x: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
 def moe_gemm(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """(E, C, d) x (E, d, f) -> (E, C, f) grouped GEMM."""
     return _mg.moe_gemm(x, w, interpret=_interpret())
-
-
-@jax.jit
-def fleet_feasibility(starts: jnp.ndarray, ends: jnp.ndarray,
-                      sizes: jnp.ndarray, n: jnp.ndarray, ps: jnp.ndarray,
-                      d: jnp.ndarray, cpu_free: jnp.ndarray, head=None):
-    """Stacked (K, N) fleet ledger -> ((K,) feasible mask, (K,) load).
-
-    The fleet simulator's cross-node admission scan fused with the
-    router's pending-work reduction; see kernels/fleet_feasibility.py.
-    ``head`` marks retired slots (fleetsim head-pointer rows; default 0).
-    """
-    with jax.named_scope("kernels.fleet_feasibility"):
-        return _ff.fleet_feasibility_fwd(starts, ends, sizes, n, ps, d,
-                                         cpu_free, head,
-                                         interpret=_interpret())
-
-
-@jax.jit
-def event_select(t_a, node_a, d_a, p_a, pay_a, avail_a,
-                 t_b, node_b, d_b, p_b, pay_b, avail_b,
-                 starts: jnp.ndarray, ends: jnp.ndarray, sizes: jnp.ndarray,
-                 n: jnp.ndarray, head, speeds: jnp.ndarray,
-                 busy: jnp.ndarray, latency: jnp.ndarray,
-                 inv_bw: jnp.ndarray):
-    """Fused next-event merge + per-hop referral scoring (DESIGN.md §7).
-
-    Candidate scalars ``(t, node, d, p, payload, avail)`` for the fresh
-    arrival (``_a``) and the re-arrival buffer head (``_b``); stacked
-    (K, N) ledger windows; full (K, K) NetParams tensors (zeros for a
-    network-free run).  Returns ``(take_fresh, t, node, feasible (K,),
-    arrive (K,), j (K,), cap (K,), load (K,))``; oracle:
-    :func:`repro.kernels.ref.event_select_ref`.
-    """
-    with jax.named_scope("kernels.event_select"):
-        return _es.event_select_fwd(t_a, node_a, d_a, p_a, pay_a, avail_a,
-                                    t_b, node_b, d_b, p_b, pay_b, avail_b,
-                                    starts, ends, sizes, n, head, speeds,
-                                    busy, latency, inv_bw,
-                                    interpret=_interpret())
-
-
-@jax.jit
-def link_cost(starts: jnp.ndarray, ends: jnp.ndarray, sizes: jnp.ndarray,
-              n: jnp.ndarray, ps: jnp.ndarray, d: jnp.ndarray,
-              busy: jnp.ndarray, head, t_src: jnp.ndarray,
-              lat_row: jnp.ndarray, inv_bw_row: jnp.ndarray,
-              payload: jnp.ndarray):
-    """Fused referral scoring: transfer delay + ledger feasibility.
-
-    One request at a source node at ``t_src`` against K candidates'
-    stacked (K, N) ledgers; ``lat_row``/``inv_bw_row`` are the source's
-    rows of the :class:`repro.netsim.NetParams` tensors.  Returns
-    ``((K,) feasible, (K,) arrival, (K,) load)``; oracle:
-    :func:`repro.kernels.ref.link_cost_ref`.
-    """
-    with jax.named_scope("kernels.link_cost"):
-        return _lc.link_cost_fwd(starts, ends, sizes, n, ps, d, busy, head,
-                                 t_src, lat_row, inv_bw_row, payload,
-                                 interpret=_interpret())

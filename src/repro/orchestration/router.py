@@ -1,13 +1,11 @@
 """Topology-aware forwarding router.
 
-The legacy policies (``repro.core.policies``) hardcode the paper's
-fully-connected cluster: candidates are "every node but me".  The
-:class:`Router` keeps the same four strategies but draws candidates from
+The :class:`Router` draws forwarding candidates from
 ``topology.neighbors(node)``, so the identical policy code drives a mesh, a
 ring, a star, or a two-tier cluster.  On a full mesh with the ``random``
-policy it consumes its rng stream exactly like the legacy
-``RandomPolicy`` — that is what keeps the simulator adapter golden-value
-equivalent to the pre-refactor event loop.
+policy it consumes its rng stream exactly like the pre-refactor event
+loop's fully-connected policy — that is what keeps the simulator adapter
+golden-value equivalent to it (``tests/golden_simulator.json``).
 
 Strategies (``Router(topology, policy=...)``):
 

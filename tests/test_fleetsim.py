@@ -1,5 +1,5 @@
-"""Fleet simulator vs the event-heap Orchestrator, and the Pallas
-fleet-feasibility kernel vs its pure-jnp oracle.
+"""Fleet simulator vs the event-heap Orchestrator, and the scan's fleet
+scorer vs the single-ledger search of ``repro.core.jax_queue``.
 
 Equivalence contract (DESIGN.md §5): per-request outcomes match the host
 engine exactly — deterministic policies replay move-for-move, stochastic
@@ -17,7 +17,7 @@ from repro.core import jax_queue as jq
 from repro.fleetsim import (DISCARDED, MET, SimParams, pack_requests,
                             simulate, simulate_fn, topology_arrays)
 from repro.fleetsim.validate import run_validation
-from repro.kernels import ops, ref
+from repro.fleetsim.scoring import fleet_search
 from repro.orchestration import Topology, UniformWorkload, get_workload
 
 # a 3-node workload deep in overload: forwards, forced pushes and late
@@ -78,14 +78,6 @@ def test_batched_feasible_scores_post_retire_state():
                          topology=Topology.full_mesh(2))
     assert rep.exact, rep.row()
     assert rep.host["forwards"] == rep.fleet["forwards"] == 0
-    # and the Pallas kernel path agrees with the reference path on it
-    reqs, _, _ = pack_requests(_Fixed().generate(0))
-    ta = topology_arrays(Topology.full_mesh(2))
-    kw = dict(policy="batched_feasible", capacity=16, depth=16)
-    a = simulate(reqs, ta, SimParams.make(0), use_pallas=False, **kw)
-    b = simulate(reqs, ta, SimParams.make(0), use_pallas=True, **kw)
-    assert np.array_equal(np.asarray(a.outcome), np.asarray(b.outcome))
-    assert int(a.forwards) == int(b.forwards) == 0
 
 
 @pytest.mark.parametrize("scenario", ["paper/scenario1"])
@@ -100,8 +92,8 @@ def test_matches_orchestrator_paper_scenario(scenario):
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel vs oracle (interpret mode off-TPU — skips nothing, the
-# kernel body lowers through the interpreter to plain XLA)
+# the scan's fleet scorer vs the router's single-ledger search, which shares
+# none of its code (a cumsum and an index where the scorer sums masks)
 # ---------------------------------------------------------------------------
 def _random_fleet(rng, K, N):
     leds = []
@@ -120,28 +112,30 @@ def _random_fleet(rng, K, N):
 
 
 @pytest.mark.parametrize("K,N", [(1, 8), (5, 16), (12, 32)])
-def test_fleet_feasibility_kernel_matches_ref(K, N):
+def test_fleet_search_matches_feasible_nodes(K, N):
     import random
     rng = random.Random(K * 31 + N)
     stacked, frees = _random_fleet(rng, K, N)
     ps = jnp.asarray([rng.choice([5.0, 20.0, 44.0, 180.0])
                       for _ in range(K)], jnp.float32)
     for d in (30.0, 400.0, 8000.0):
-        got_f, got_l = ops.fleet_feasibility(
-            stacked.starts, stacked.ends, stacked.sizes, stacked.n, ps,
-            jnp.float32(d), frees)
-        want_f, want_l = ref.fleet_feasibility_ref(
+        got_f, got_j, got_cap, got_l = fleet_search(
             stacked.starts, stacked.ends, stacked.sizes, stacked.n, ps,
             jnp.float32(d), frees)
         base = jq.feasible_nodes(stacked, ps, jnp.float32(d), frees) \
             & (stacked.n < N)
-        assert np.array_equal(np.asarray(got_f), np.asarray(want_f)), d
+        _, want_j, want_cap = jax.vmap(
+            lambda led, p, cf: jq._search(led, p, jnp.float32(d), cf))(
+                stacked, ps, frees)
         assert np.array_equal(np.asarray(got_f), np.asarray(base)), d
-        np.testing.assert_allclose(np.asarray(got_l), np.asarray(want_l),
+        assert np.array_equal(np.asarray(got_j), np.asarray(want_j)), d
+        assert np.array_equal(np.asarray(got_cap), np.asarray(want_cap)), d
+        np.testing.assert_allclose(np.asarray(got_l),
+                                   np.asarray(stacked.sizes).sum(axis=1),
                                    rtol=1e-6)
 
 
-def test_fleet_feasibility_kernel_head_pointer_rows():
+def test_fleet_search_head_pointer_rows():
     """Retired-slot prefixes (-BIG/0 + head offset) give the same verdict
     as the equivalent compacted plain ledger."""
     led = jq.empty_ledger(16)
@@ -153,23 +147,13 @@ def test_fleet_feasibility_kernel_head_pointer_rows():
     ends = jnp.concatenate([jnp.full((h,), -jq.BIG), led.ends[:-h]])
     sizes = jnp.concatenate([jnp.zeros((h,)), led.sizes[:-h]])
     for ps, d in ((5.0, 60.0), (44.0, 300.0), (180.0, 9000.0), (500.0, 200.0)):
-        got, _ = ops.fleet_feasibility(
+        got, _, _, _ = fleet_search(
             starts[None], ends[None], sizes[None], led.n[None],
             jnp.float32(ps)[None], jnp.float32(d), jnp.zeros((1,)),
             jnp.array([h], jnp.int32))
         want = jq.feasible(led, jnp.float32(ps), jnp.float32(d),
                            jnp.float32(0.0))
         assert bool(got[0]) == bool(want), (ps, d)
-
-
-def test_simulate_use_pallas_matches_ref_path():
-    reqs, _, _ = pack_requests(HOT.generate(0))
-    ta = topology_arrays(Topology.full_mesh(3))
-    kw = dict(policy="batched_feasible", capacity=256, depth=128)
-    a = simulate(reqs, ta, SimParams.make(0), use_pallas=False, **kw)
-    b = simulate(reqs, ta, SimParams.make(0), use_pallas=True, **kw)
-    assert np.array_equal(np.asarray(a.outcome), np.asarray(b.outcome))
-    assert int(a.met_deadline) == int(b.met_deadline)
 
 
 @pytest.mark.parametrize("C,W", [(1024, 512), (256, 128), (128, 128),

@@ -131,7 +131,7 @@ def _compile(case: str, sharding):
         lowered = jax.jit(sweep).lower(reqs, topo, params, targets, net)
     else:          # as simulate() calls it
         lowered = _simulate.lower(reqs, topo, params, targets, net,
-                                  use_pallas=False, use_network=True, **kw)
+                                  use_network=True, **kw)
     return lowered.compile().as_text()
 
 

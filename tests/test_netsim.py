@@ -1,6 +1,6 @@
 """netsim: wire-cost semantics, zero-cost equivalence with BOTH
-orchestration cores, the fused link_cost kernel vs its oracle, and the
-latency-never-helps property.
+orchestration cores, the fleet scan's per-hop scorer vs the single-ledger
+search, and the latency-never-helps property.
 
 The equivalence contract (DESIGN.md §6): a zero-cost network
 (``LinkModel.zero`` / ``NetParams.zero``) must reproduce the network-free
@@ -31,7 +31,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp                                     # noqa: E402
 
 from repro.core import jax_queue as jq                      # noqa: E402
-from repro.kernels import ops, ref                          # noqa: E402
+from repro.fleetsim.scoring import event_select             # noqa: E402
 
 # the fleetsim test suite's overloaded 3-node workload: forwards, forced
 # pushes and late completions all exercised
@@ -333,28 +333,6 @@ class TestFleetsimNet:
                               np.asarray(b.served_by))
         assert int(a.forwards) == int(b.forwards)
 
-    def test_zero_net_pallas_path_identical(self):
-        reqs, _, _ = pack_requests(HOT.generate(0))
-        ta = topology_arrays(Topology.full_mesh(3))
-        kw = dict(policy="batched_feasible", capacity=256, depth=128,
-                  use_pallas=True)
-        a = simulate(reqs, ta, SimParams.make(0), **kw)
-        b = simulate(reqs, ta, SimParams.make(0), net=NetParams.zero(3), **kw)
-        assert np.array_equal(np.asarray(a.outcome), np.asarray(b.outcome))
-
-    def test_priced_net_pallas_matches_ref_path(self):
-        """The fused link_cost kernel inside the scan == the jnp oracle
-        inside the scan, outcome-for-outcome."""
-        reqs, _, _ = pack_requests(HOT.generate(0))
-        ta = topology_arrays(Topology.full_mesh(3))
-        _, lm = paper_campus(3)
-        kw = dict(policy="batched_feasible", capacity=256, depth=128,
-                  net=lm.net_params())
-        a = simulate(reqs, ta, SimParams.make(0), use_pallas=False, **kw)
-        b = simulate(reqs, ta, SimParams.make(0), use_pallas=True, **kw)
-        assert np.array_equal(np.asarray(a.outcome), np.asarray(b.outcome))
-        assert int(a.met_deadline) == int(b.met_deadline)
-
     def test_latency_ladder_matches_host_rung_for_rung(self):
         """The event-time scan inherits the host's full priced dynamics —
         including the non-monotone deep-overload regime where a delayed
@@ -442,7 +420,9 @@ class TestFleetsimNet:
 
 
 # ---------------------------------------------------------------------------
-# the fused link_cost kernel vs its oracle (bit-for-bit)
+# the fleet scan's per-hop scorer vs the router's single-ledger search
+# (jax_queue.feasible_nodes, which shares none of its code) at each
+# candidate's wire-delayed arrival
 # ---------------------------------------------------------------------------
 def _random_fleet(rng, K, N):
     leds, frees = [], []
@@ -459,52 +439,82 @@ def _random_fleet(rng, K, N):
     return stacked, jnp.asarray(frees, jnp.float32)
 
 
+def _net(rng, K):
+    """(K, K) latency / inverse-bandwidth tensors with a zero diagonal."""
+    lat = [[0.0 if i == j else rng.uniform(0.0, 120.0) for j in range(K)]
+           for i in range(K)]
+    ibw = [[0.0 if i == j else rng.choice([0.0, 0.1, 1.0]) for j in range(K)]
+           for i in range(K)]
+    return (jnp.asarray(np.asarray(lat, np.float32)),
+            jnp.asarray(np.asarray(ibw, np.float32)))
+
+
+def _fresh(t, node, d, p, payload):
+    """Scalar candidates of a lone fresh arrival (the buffer is empty)."""
+    return (jnp.float32(t), jnp.int32(node), jnp.float32(d), jnp.float32(p),
+            jnp.float32(payload), True,
+            jnp.float32(0.0), jnp.int32(0), jnp.float32(0.0),
+            jnp.float32(0.0), jnp.float32(0.0), False)
+
+
+def _assert_columns(sel, stacked, busy, speeds, lat, ibw, t, node, d, p,
+                    payload):
+    """Every column of ``event_select`` is the single-ledger search at
+    ``max(t + latency[node] + payload * inv_bw[node], busy)``."""
+    N = stacked.starts.shape[-1]
+    ps = jnp.float32(p) / speeds
+    arrive = jnp.float32(t) + lat[node] + jnp.float32(payload) * ibw[node]
+    free = jnp.maximum(arrive, busy)
+    want_f = jq.feasible_nodes(stacked, ps, jnp.float32(d), free) \
+        & (stacked.n < N)
+    _, want_j, want_cap = jax.vmap(
+        lambda led, p1, cf: jq._search(led, p1, jnp.float32(d), cf))(
+            stacked, ps, free)
+    _, _, _, feas, arr, j, cap, load = sel
+    assert np.array_equal(np.asarray(feas), np.asarray(want_f))
+    assert np.array_equal(np.asarray(arr), np.asarray(arrive))
+    assert np.array_equal(np.asarray(j), np.asarray(want_j))
+    assert np.array_equal(np.asarray(cap), np.asarray(want_cap))
+    np.testing.assert_allclose(np.asarray(load),
+                               np.asarray(stacked.sizes).sum(axis=1),
+                               rtol=1e-6)
+
+
 @pytest.mark.parametrize("K,N", [(1, 8), (5, 16), (12, 32)])
-def test_link_cost_kernel_matches_ref(K, N):
+def test_event_select_priced_hop_matches_feasible_nodes(K, N):
     rng = random.Random(K * 131 + N)
     stacked, busy = _random_fleet(rng, K, N)
-    ps = jnp.asarray([rng.choice([5.0, 20.0, 44.0, 180.0])
-                      for _ in range(K)], jnp.float32)
-    lat = jnp.asarray([rng.uniform(0.0, 120.0) for _ in range(K)],
-                      jnp.float32)
-    ibw = jnp.asarray([rng.choice([0.0, 0.1, 1.0]) for _ in range(K)],
-                      jnp.float32)
+    speeds = jnp.asarray([rng.choice([0.5, 1.0, 2.0]) for _ in range(K)],
+                         jnp.float32)
+    lat, ibw = _net(rng, K)
     for d, t, payload in ((400.0, 10.0, 24.8832), (8000.0, 120.0, 2.0736),
                           (60.0, 0.0, 0.9216)):
-        got = ops.link_cost(stacked.starts, stacked.ends, stacked.sizes,
-                            stacked.n, ps, jnp.float32(d), busy, None,
-                            jnp.float32(t), lat, ibw, jnp.float32(payload))
-        want = ref.link_cost_ref(stacked.starts, stacked.ends, stacked.sizes,
-                                 stacked.n, ps, jnp.float32(d), busy, None,
-                                 jnp.float32(t), lat, ibw,
-                                 jnp.float32(payload))
-        assert np.array_equal(np.asarray(got[0]), np.asarray(want[0])), d
-        assert np.array_equal(np.asarray(got[1]), np.asarray(want[1])), d
-        np.testing.assert_allclose(np.asarray(got[2]), np.asarray(want[2]),
-                                   rtol=1e-6)
+        node, p = rng.randrange(K), rng.choice([5.0, 20.0, 44.0, 180.0])
+        sel = event_select(*_fresh(t, node, d, p, payload), stacked.starts,
+                           stacked.ends, stacked.sizes, stacked.n, None,
+                           speeds, busy, lat, ibw)
+        assert bool(sel[0]) and float(sel[1]) == t and int(sel[2]) == node
+        _assert_columns(sel, stacked, busy, speeds, lat, ibw, t, node, d, p,
+                        payload)
 
 
-def test_link_cost_zero_delay_degenerates_to_fleet_feasibility():
+def test_event_select_zero_delay_degenerates_to_feasible_nodes():
     rng = random.Random(42)
     K, N = 6, 16
     stacked, busy = _random_fleet(rng, K, N)
-    ps = jnp.full((K,), 20.0, jnp.float32)
-    zeros = jnp.zeros((K,), jnp.float32)
+    zeros = jnp.zeros((K, K), jnp.float32)
+    ones = jnp.ones((K,), jnp.float32)
     for d, t in ((300.0, 0.0), (4000.0, 55.0)):
-        got, arr, load = ops.link_cost(
-            stacked.starts, stacked.ends, stacked.sizes, stacked.n, ps,
-            jnp.float32(d), busy, None, jnp.float32(t), zeros, zeros,
-            jnp.float32(24.8))
-        base_f, base_l = ops.fleet_feasibility(
-            stacked.starts, stacked.ends, stacked.sizes, stacked.n, ps,
-            jnp.float32(d), jnp.maximum(jnp.float32(t), busy))
-        assert np.array_equal(np.asarray(got), np.asarray(base_f))
-        np.testing.assert_allclose(np.asarray(load), np.asarray(base_l))
-        np.testing.assert_allclose(np.asarray(arr),
+        sel = event_select(*_fresh(t, 1, d, 20.0, 24.8), stacked.starts,
+                           stacked.ends, stacked.sizes, stacked.n, None,
+                           ones, busy, zeros, zeros)
+        _assert_columns(sel, stacked, busy, ones, zeros, zeros, t, 1, d,
+                        20.0, 24.8)
+        np.testing.assert_allclose(np.asarray(sel[4]),
                                    np.full((K,), t, np.float32))
 
 
-def test_link_cost_head_pointer_rows():
+def test_event_select_head_pointer_rows():
     """Retired-slot prefixes give the same verdict as the compacted plain
     ledger, with the arrival delay applied on top."""
     led = jq.empty_ledger(16)
@@ -516,31 +526,27 @@ def test_link_cost_head_pointer_rows():
     sizes = jnp.concatenate([jnp.zeros((h,)), led.sizes[:-h]])
     for ps, d, lam in ((5.0, 60.0, 0.0), (5.0, 60.0, 30.0),
                        (44.0, 300.0, 10.0), (180.0, 9000.0, 100.0)):
-        got, _, _ = ops.link_cost(
-            starts[None], ends[None], sizes[None], led.n[None],
-            jnp.float32(ps)[None], jnp.float32(d), jnp.zeros((1,)),
-            jnp.array([h], jnp.int32), jnp.float32(0.0),
-            jnp.float32(lam)[None], jnp.zeros((1,)), jnp.float32(0.0))
+        sel = event_select(*_fresh(0.0, 0, d, ps, 0.0), starts[None],
+                           ends[None], sizes[None], led.n[None],
+                           jnp.array([h], jnp.int32), jnp.ones((1,)),
+                           jnp.zeros((1,)), jnp.full((1, 1), lam),
+                           jnp.zeros((1, 1)))
         want = jq.feasible(led, jnp.float32(ps), jnp.float32(d),
                            jnp.float32(lam))
-        assert bool(got[0]) == bool(want), (ps, d, lam)
+        assert bool(sel[3][0]) == bool(want), (ps, d, lam)
 
 
-# ---------------------------------------------------------------------------
-# the fused event_select kernel vs its oracle (bit-for-bit)
-# ---------------------------------------------------------------------------
 @pytest.mark.parametrize("K,N", [(1, 8), (5, 16), (12, 32)])
 def test_event_select_kernel_matches_ref(K, N):
+    """The merge follows the heap's ``(time, seq)`` rule written out here
+    (fresh wins ties; an empty buffer or an exhausted stream leaves the
+    other candidate), and the chosen event's columns are the single-ledger
+    search at its wire-delayed arrival."""
     rng = random.Random(K * 17 + N)
     stacked, busy = _random_fleet(rng, K, N)
     speeds = jnp.asarray([rng.choice([0.5, 1.0, 2.0]) for _ in range(K)],
                          jnp.float32)
-    lat = jnp.asarray(np.asarray(
-        [[0.0 if i == j else rng.uniform(0.0, 120.0) for j in range(K)]
-         for i in range(K)], np.float32))
-    ibw = jnp.asarray(np.asarray(
-        [[0.0 if i == j else rng.choice([0.0, 0.1, 1.0]) for j in range(K)]
-         for i in range(K)], np.float32))
+    lat, ibw = _net(rng, K)
     cases = [
         # (t_a, avail_a, t_b, avail_b): both live, tie (fresh must win),
         # buffer earlier, fresh only, buffer only, neither
@@ -549,23 +555,23 @@ def test_event_select_kernel_matches_ref(K, N):
         (3.0, False, 55.0, True), (1.0, False, 2.0, False),
     ]
     for t_a, av_a, t_b, av_b in cases:
-        args = (jnp.float32(t_a), jnp.int32(rng.randrange(K)),
-                jnp.float32(rng.uniform(50, 9000)), jnp.float32(20.0),
-                jnp.float32(rng.choice([0.92, 24.88])), av_a,
-                jnp.float32(t_b), jnp.int32(rng.randrange(K)),
-                jnp.float32(rng.uniform(50, 9000)), jnp.float32(44.0),
-                jnp.float32(rng.choice([0.92, 24.88])), av_b,
-                stacked.starts, stacked.ends, stacked.sizes, stacked.n,
-                None, speeds, busy, lat, ibw)
-        got = ops.event_select(*args)
-        want = ref.event_select_ref(*args)
-        assert bool(got[0]) == bool(want[0])              # take_fresh
-        if av_a and av_b and t_a == t_b:
-            assert bool(got[0])                           # fresh wins ties
-        assert float(got[1]) == float(want[1])            # t
-        assert int(got[2]) == int(want[2])                # node
-        for g, w in zip(got[3:], want[3:]):               # feas/arrive/j/
-            assert np.array_equal(np.asarray(g), np.asarray(w))  # cap/load
+        a = (t_a, rng.randrange(K), rng.uniform(50, 9000), 20.0,
+             rng.choice([0.92, 24.88]))
+        b = (t_b, rng.randrange(K), rng.uniform(50, 9000), 44.0,
+             rng.choice([0.92, 24.88]))
+        cand = lambda c, av: (jnp.float32(c[0]), jnp.int32(c[1]),
+                              jnp.float32(c[2]), jnp.float32(c[3]),
+                              jnp.float32(c[4]), av)
+        sel = event_select(*cand(a, av_a), *cand(b, av_b), stacked.starts,
+                           stacked.ends, stacked.sizes, stacked.n, None,
+                           speeds, busy, lat, ibw)
+        take_fresh = av_a and (t_a <= t_b or not av_b)
+        won = a if take_fresh else b
+        assert bool(sel[0]) == take_fresh, (t_a, av_a, t_b, av_b)
+        assert float(sel[1]) == won[0]
+        assert int(sel[2]) == won[1]
+        _assert_columns(sel, stacked, busy, speeds, lat, ibw, won[0], won[1],
+                        won[2], won[3], won[4])
 
 
 def test_event_select_zero_net_scores_event_node_at_true_arrival():
@@ -577,17 +583,16 @@ def test_event_select_zero_net_scores_event_node_at_true_arrival():
     zeros = jnp.zeros((K, K), jnp.float32)
     ones = jnp.ones((K,), jnp.float32)
     for t, d in ((10.0, 300.0), (55.0, 4000.0)):
-        sel = ref.event_select_ref(
+        sel = event_select(
             jnp.float32(t), jnp.int32(2), jnp.float32(d), jnp.float32(20.0),
             jnp.float32(1.0), True,
             jnp.float32(t + 1), jnp.int32(0), jnp.float32(d),
             jnp.float32(20.0), jnp.float32(1.0), True,
             stacked.starts, stacked.ends, stacked.sizes, stacked.n, None,
             ones, busy, zeros, zeros)
-        base_f, _ = ops.fleet_feasibility(
-            stacked.starts, stacked.ends, stacked.sizes, stacked.n,
-            jnp.full((K,), 20.0, jnp.float32), jnp.float32(d),
-            jnp.maximum(jnp.float32(t), busy))
+        base_f = jq.feasible_nodes(
+            stacked, jnp.full((K,), 20.0, jnp.float32), jnp.float32(d),
+            jnp.maximum(jnp.float32(t), busy)) & (stacked.n < N)
         assert np.array_equal(np.asarray(sel[3]), np.asarray(base_f))
         np.testing.assert_allclose(np.asarray(sel[4]),
                                    np.full((K,), t, np.float32))
